@@ -43,6 +43,8 @@ def main() -> None:
     ap.add_argument("--out", default="experiments/bench")
     args, _ = ap.parse_known_args()
     trials = 1 if args.quick else 2
+    from repro.utils.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     from benchmarks.paper_figs import bench_fig1, bench_fig2
     from benchmarks.complexity import (bench_complexity_table,

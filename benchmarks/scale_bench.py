@@ -16,12 +16,12 @@
     scrambled-labeling cluster-of-cliques graph where RCM recovers the
     banded structure.
   * section="virtual_mesh": the virtual-node mesh tier at the same
-    L=100k (quick: 10k) on 8 fake host devices — three NON-gossip
-    programs (exact_diffusion's ψ-corrected combine, dif_topk's
-    compressed wire, dif_partial's masked dropout combine) through
-    the one program lowering, via the runner's mesh dispatch.  Runs in
-    a subprocess because the fake device count is fixed at process
-    start.
+    L=100k (quick: 10k) — three NON-gossip programs (exact_diffusion's
+    ψ-corrected combine, dif_topk's compressed wire, dif_partial's
+    masked dropout combine) through the one program lowering, via the
+    runner's mesh dispatch.  On a TPU host it runs in-process over the
+    chips; on CPU in a subprocess over 8 fake host devices, because the
+    fake device count is fixed at process start.
 """
 from __future__ import annotations
 
@@ -152,18 +152,13 @@ def bench_rcm(quick: bool = False):
     return rows
 
 
-_VIRTUAL_SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import json, resource, sys, time
-    import jax
-    jax.config.update("jax_enable_x64", True)
+def virtual_mesh_rows(L: int):
+    """The virtual_mesh rows on whatever devices this process sees."""
     import dataclasses
     from repro.api.runner import materialize, run_experiment
     from repro.api.spec import (ExperimentSpec, InitSpec, ProblemSpec,
                                 SolverSpec, TopologySpec)
 
-    L = int(sys.argv[1])
     base = ExperimentSpec(
         problem=ProblemSpec(d=16, T=L, r=2, n=8, L=L, kappa=1.2),
         topology=TopologySpec(family="barabasi_albert", ba_m=3, seed=0,
@@ -198,21 +193,35 @@ _VIRTUAL_SCRIPT = textwrap.dedent("""
             "n_edges": int(mat.graph.n_edges),
             "us_per_iter": warm_s / spec.solver.T_GD * 1e6,
             "first_run_s": round(total_s, 3),
-            "peak_rss_mb": round(
-                resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-                / 1024.0, 1),
+            "peak_rss_mb": round(_peak_rss_mb(), 1),
             "sd_max_final": float(trace.sd_max[-1]),
         })
-    print("ROWS=" + json.dumps(rows))
+    return rows
+
+
+# CPU only: the 8 fake host devices must be fixed before jax starts, so
+# the rows run in a child that imports nothing before setting them
+_VIRTUAL_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import json, sys
+    import jax
+    jax.config.update("jax_enable_x64", True)
+    from benchmarks.scale_bench import virtual_mesh_rows
+    print("ROWS=" + json.dumps(virtual_mesh_rows(int(sys.argv[1]))))
 """)
 
 
 def bench_virtual_mesh(quick: bool = False):
-    """Virtual-node mesh tier rows — non-gossip programs at large L on
-    8 fake host devices (subprocess: device count is process-fixed)."""
+    """Virtual-node mesh tier rows — non-gossip programs at large L.  On
+    a TPU host they run in this process over its chips (a child could
+    not reach a chip this process holds); elsewhere on 8 fake host
+    devices in a child process."""
     import os
 
     L = 10_000 if quick else 100_000
+    if jax.default_backend() == "tpu":
+        return virtual_mesh_rows(L)
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     r = subprocess.run([sys.executable, "-c", _VIRTUAL_SCRIPT, str(L)],

@@ -16,7 +16,9 @@ from_json`` accepts, including SystemSpec fault schedules.
         --jobs 4
 
 ``--in-process`` runs the cells in this process (no subprocess spawn) —
-the test-suite path, and useful under a debugger.
+the test-suite path, and useful under a debugger.  A TPU chip belongs to
+one process at a time, so ``--jobs > 1`` is refused where the cells
+would run on a TPU.
 """
 from __future__ import annotations
 
@@ -103,9 +105,24 @@ def cmd_emit(args) -> None:
     print(f"wrote {len(cells)} cells to {args.out}")
 
 
+def _child_platform() -> str:
+    """JAX's default backend as a cell child would see it — asked of a
+    short-lived child, so this parent never holds a chip."""
+    proc = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode != 0:
+        raise RuntimeError(f"platform probe failed:\n{proc.stderr[-2000:]}")
+    return proc.stdout.strip().splitlines()[-1]
+
+
 def cmd_run(args) -> None:
     with open(args.specs) as f:
         cells = json.load(f)
+    if not args.in_process and args.jobs > 1 and _child_platform() == "tpu":
+        raise SystemExit("benchmarks.sweep: --jobs > 1 would start several "
+                         "processes on one TPU host, and a chip belongs to "
+                         "one process at a time; use --jobs 1")
     worker = run_cell if args.in_process else _run_cell_subprocess
     if args.in_process or args.jobs <= 1:
         results = [worker(c) for c in cells]
@@ -121,6 +138,8 @@ def cmd_run(args) -> None:
 
 
 def cmd_cell(args) -> None:
+    from repro.utils.compile_cache import use_persistent_cache
+    use_persistent_cache()
     with open(args.spec) as f:
         cell = json.load(f)
     print(json.dumps(run_cell(cell)))
@@ -140,7 +159,8 @@ def main(argv=None) -> None:
     p = sub.add_parser("run", help="execute a grid, one process per cell")
     p.add_argument("--specs", required=True, help="JSON grid from emit")
     p.add_argument("--out", required=True, help="merged CSV path")
-    p.add_argument("--jobs", type=int, default=2)
+    p.add_argument("--jobs", type=int, default=1,
+                   help="cells run at once (CPU only: >1 is refused on TPU)")
     p.add_argument("--in-process", action="store_true",
                    help="run cells in this process (tests / debugging)")
     p.set_defaults(fn=cmd_run)

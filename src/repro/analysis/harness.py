@@ -99,14 +99,14 @@ def _setup(L: int, dtype_name: str):
 
 
 def _mesh8():
-    from repro.utils.compat import make_mesh
     if len(jax.devices()) < N_DEV:
         raise RuntimeError(
             f"the mesh/virtual traces need {N_DEV} devices (have "
             f"{len(jax.devices())}); run via `python -m tools.reprolint`, "
             f"which sets XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{N_DEV} before importing jax")
-    return make_mesh((N_DEV,), ("nodes",))
+    return jax.make_mesh((N_DEV,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def trace_program(name: str, substrate: str, dtype=jnp.float32) -> Trace:
@@ -214,7 +214,7 @@ def eqn_location(eqn):
     traced this eqn, or ('', '', 0) when jax has no source info."""
     try:
         from jax._src import source_info_util
-        fr = source_info_util.user_frame(eqn.source_info)
+        fr = source_info_util.user_frame(eqn.source_info.traceback)
         if fr is None:
             return "", "", 0
         path = fr.file_name

@@ -44,7 +44,6 @@ from repro.core.problem import (MTRLProblem, generate_problem, node_view,
 from repro.core.spectral import SpectralInit, decentralized_spectral_init
 from repro.distributed import consensus as _consensus
 from repro.distributed.graphs import Graph, SparseGraph
-from repro.utils.compat import make_mesh
 
 
 _COMM_MODELS = {"ethernet-1gbps": _cm.ETHERNET_1GBPS,
@@ -397,7 +396,8 @@ def _run_mesh(spec: ExperimentSpec, solver: SolverDef, mat: Materialized,
                          f"L={p.L} but {n_dev} devices are available "
                          f"(the virtual-node tier needs a solver with a "
                          f"virtual mesh runtime and n_dev | L)")
-    mesh = make_mesh((p.L,), ("nodes",))
+    mesh = jax.make_mesh((p.L,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     kw = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
     if avail is not None:
         kw.update(avail=jnp.asarray(avail))
@@ -438,7 +438,8 @@ def _run_virtual_mesh(spec: ExperimentSpec, solver: SolverDef,
     if not isinstance(W, SparseWeights):
         W = SparseWeights.from_dense(np.asarray(W))
     vt = _consensus.VirtualTopology.from_weights(W, n_dev)
-    mesh = make_mesh((n_dev,), ("nodes",))
+    mesh = jax.make_mesh((n_dev,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     kw = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
     if avail is not None:
         kw.update(avail=jnp.asarray(avail))

@@ -52,7 +52,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import AltgdminEngine, resolve_engine
 from repro.core.metrics import consensus_spread, subspace_distance
-from repro.utils.compat import shard_map as _shard_map
 
 
 def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
@@ -131,12 +130,14 @@ def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
 
     sharded = P(axis_name)
     out_specs = ((sharded,) * 4) if with_metrics else (sharded, sharded)
-    run = _shard_map(body, mesh=mesh,
-                     in_specs=(sharded, sharded, sharded, P())
-                     + ((P(),) if has_xs else ()),
-                     out_specs=out_specs,
-                     axis_names={axis_name},
-                     check_rep=not eng.fused)
+    # a pallas_call's outputs carry no varying-axes type, so the check
+    # is off exactly when the engine dispatches Pallas kernels
+    run = jax.shard_map(body, mesh=mesh,
+                        in_specs=(sharded, sharded, sharded, P())
+                        + ((P(),) if has_xs else ()),
+                        out_specs=out_specs,
+                        axis_names={axis_name},
+                        check_vma=not eng.fused)
 
     U_dummy = U0[0] if U_star is None else U_star
     out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
@@ -213,12 +214,14 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
 
     sharded = P(axis_name)
     out_specs = ((sharded,) * 4) if with_metrics else (sharded, sharded)
-    run = _shard_map(body, mesh=mesh,
-                     in_specs=(sharded, sharded, sharded, P())
-                     + ((P(),) if has_xs else ()),
-                     out_specs=out_specs,
-                     axis_names={axis_name},
-                     check_rep=not eng.fused)
+    # a pallas_call's outputs carry no varying-axes type, so the check
+    # is off exactly when the engine dispatches Pallas kernels
+    run = jax.shard_map(body, mesh=mesh,
+                        in_specs=(sharded, sharded, sharded, P())
+                        + ((P(),) if has_xs else ()),
+                        out_specs=out_specs,
+                        axis_names={axis_name},
+                        check_vma=not eng.fused)
 
     U_dummy = U0[0] if U_star is None else U_star
     out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))

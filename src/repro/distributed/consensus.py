@@ -1128,7 +1128,9 @@ class CompressedGossipCombine(GossipCombine):
         of each shift-neighbour's x̂ (what the neighbour's payloads have
         built up), all zero-initialized."""
         own = jnp.zeros_like(z_local[None])
-        nbrs = jnp.zeros((n_shifts,) + own.shape, own.dtype)
+        # broadcast from ``own`` so the buffers inherit z_local's
+        # varying-axes type inside shard_map (the scan carry needs it)
+        nbrs = jnp.broadcast_to(own, (n_shifts,) + own.shape)
         if self._stochastic(**kw):
             return (own, nbrs, jnp.zeros((), jnp.int32))
         return own, nbrs
@@ -1178,8 +1180,10 @@ class CompressedGossipCombine(GossipCombine):
                 vals = jnp.asarray(vals, Z.dtype)
                 w_diag = jnp.asarray(diag, Z.dtype)[:, None, None]
             else:
-                w_diag = jnp.diag(jnp.asarray(W)) \
-                    .astype(Z.dtype)[:, None, None]
+                # host-side: W is concrete here, and jnp.diag would
+                # trace an (L, L) iota mask
+                w_diag = jnp.asarray(np.diag(np.asarray(W)),
+                                     Z.dtype)[:, None, None]
 
             def round_(carry, _):
                 Zc, st = carry
@@ -2065,7 +2069,9 @@ class PushSumGossipCombine(MaskedGossipCombine):
             c = w[0] + jnp.sum(w[1:] * keep)
             c = jnp.where(c > 0, c, 1.0)
             w_eff = jnp.concatenate([w[:1], w[1:] * keep])
-            wv0 = jnp.ones((), z.dtype)
+            # the companion weight varies per device once the rounds run
+            wv0 = jax.lax.pcast(jnp.ones((), z.dtype), axis_name,
+                                to="varying")
 
             def round_(carry, _):
                 zc, wv = carry
@@ -2129,7 +2135,8 @@ class PushSumGossipCombine(MaskedGossipCombine):
                                             indices_are_sorted=True)[:V]
             c = jnp.where(c > 0, c, 1.0)
             flat = z.reshape(V, -1)
-            w0 = jnp.ones((V, 1), z.dtype)
+            w0 = jax.lax.pcast(jnp.ones((V, 1), z.dtype), axis_name,
+                               to="varying")
 
             def round_(carry, _):
                 zf, wv = carry

@@ -1,5 +1,5 @@
-"""AltGDmin least-squares Pallas kernel — the paper's own compute hot loop
-(Algorithm 3 lines 8 & 11), adapted for the MXU.
+"""AltGDmin least-squares Pallas kernels — the paper's own compute hot
+loop (Algorithm 3 lines 8 & 11), adapted for the MXU.
 
 Per outer iteration every node evaluates, for each local task t:
     A_t = X_t U          (n×r tall-skinny),
@@ -7,20 +7,22 @@ Per outer iteration every node evaluates, for each local task t:
 and, for the gradient, X_tᵀ(A_t b_t − y_t) b_tᵀ.  The d dimension (600 in
 the paper's experiments, arbitrary in production) is the long streamed
 axis: X_t tiles of (n, blk_d) and U tiles of (blk_d, r) stream through
-VMEM while the (n, r) A-tile accumulates in scratch.  Tasks ride the
-parallel grid dimension.  The tiny r×r Cholesky solve stays in jnp
-(ops.py) — it is not MXU work.
+VMEM while the (n, r) A-tile accumulates in scratch.
 
-Layouts: X (T, n, d); U (d, r); y (T, n) → G (T, r, r), c (T, r).
+Public layout (node-batched): X (L, tpn, n, d), per-node U (L, d, r),
+y (L, tpn, n).  All L·tpn task systems ride one grid axis, so a whole
+outer iteration — Gram, r×r solve, residual and gradient tiles — is ONE
+``pallas_call`` (``node_fused_iter``), and the streamed A = X_t U
+accumulator is built exactly once per task (the standalone gradient
+kernel rebuilds it in its pass 0; the fused kernel reuses the min-step
+accumulator, saving one of the three HBM sweeps over X and ~43% of the
+model FLOPs at the paper's r=4 shape).
 
-Node-batched fused engine (the production hot path): X (L, tpn, n, d),
-per-node U (L, d, r), y (L, tpn, n).  All L·tpn task systems ride one
-grid so a whole outer iteration — Gram, r×r solve, residual and gradient
-tiles — is ONE ``pallas_call``, and the streamed A = X_t U accumulator is
-built exactly once per task (the standalone gradient kernel rebuilds it
-in its pass 0; the fused kernel reuses the min-step accumulator, saving
-one of the three HBM sweeps over X and ~43% of the model FLOPs at the
-paper's r=4 shape).
+Kernel layout: the wrappers flatten the task axis to N = L·tpn and give
+each per-task vector a singleton axis — y (N, 1, n), B / c (N, 1, r) —
+so every block's last two dims are either the array's own or (8, 128)
+multiples, as the TPU lowering requires.  Grid cell t reads U block
+t // tpn.  The reshapes are free (row-major contiguous).
 """
 from __future__ import annotations
 
@@ -32,132 +34,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gram_kernel(x_ref, u_ref, y_ref, g_ref, c_ref, a_scr):
-    di = pl.program_id(1)
-    nd = pl.num_programs(1)
-
-    @pl.when(di == 0)
-    def _init():
-        a_scr[...] = jnp.zeros_like(a_scr)
-
-    x = x_ref[0].astype(jnp.float32)             # (n, blk_d)
-    u = u_ref[...].astype(jnp.float32)           # (blk_d, r)
-    a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
-
-    @pl.when(di == nd - 1)
-    def _finalize():
-        a = a_scr[...]                           # (n, r)
-        y = y_ref[0].astype(jnp.float32)         # (n,)
-        g_ref[0] = jax.lax.dot_general(a, a, (((0,), (0,)), ((), ())))
-        c_ref[0] = jax.lax.dot_general(y[None, :], a,
-                                       (((1,), (0,)), ((), ())))[0]
-
-
-def task_gram(X, U, y, *, blk_d: int = 256, interpret: bool = True):
-    """X: (T,n,d); U: (d,r); y: (T,n) → (G (T,r,r), c (T,r)).
-    d must be a multiple of blk_d (ops.py pads)."""
-    T, n, d = X.shape
-    r = U.shape[1]
+def _check_blk(d: int, blk_d: int) -> int:
     blk_d = min(blk_d, d)
     if d % blk_d:
         raise ValueError(f"d={d} must be a multiple of blk_d={blk_d} "
                          f"(ops.py pads)")
-    grid = (T, d // blk_d)
-
-    return pl.pallas_call(
-        _gram_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n, blk_d), lambda t, i: (t, 0, i)),
-            pl.BlockSpec((blk_d, r), lambda t, i: (i, 0)),
-            pl.BlockSpec((1, n), lambda t, i: (t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, r, r), lambda t, i: (t, 0, 0)),
-            pl.BlockSpec((1, r), lambda t, i: (t, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, r, r), jnp.float32),
-            jax.ShapeDtypeStruct((T, r), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((n, r), jnp.float32)],
-        interpret=interpret,
-    )(X, U, y)
+    return blk_d
 
 
-def _grad_kernel(x_ref, u_ref, b_ref, y_ref, g_ref, a_scr, r_scr, *,
-                 n: int):
-    """Two passes over d per task (grid dims: task, pass, d-tile):
-    pass 0 accumulates A = X U; pass 1 computes resid = A b − y once, then
-    accumulates the (blk_d, r) gradient tile X_tileᵀ resid bᵀ directly into
-    the output (gradient tiles are disjoint across d)."""
-    pi, di = pl.program_id(1), pl.program_id(2)
-    nd = pl.num_programs(2)
-
-    @pl.when((pi == 0) & (di == 0))
-    def _init():
-        a_scr[...] = jnp.zeros_like(a_scr)
-
-    @pl.when(pi == 0)
-    def _accum_a():
-        x = x_ref[0].astype(jnp.float32)
-        u = u_ref[...].astype(jnp.float32)
-        a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
-
-    @pl.when((pi == 1) & (di == 0))
-    def _resid():
-        b = b_ref[0].astype(jnp.float32)             # (r,)
-        y = y_ref[0].astype(jnp.float32)             # (n,)
-        r_scr[...] = (jax.lax.dot_general(
-            a_scr[...], b[:, None], (((1,), (0,)), ((), ())))[:, 0]
-            - y)[:, None]                            # (n, 1)
-
-    @pl.when(pi == 1)
-    def _grad_tile():
-        x = x_ref[0].astype(jnp.float32)             # (n, blk_d)
-        b = b_ref[0].astype(jnp.float32)             # (r,)
-        xtres = jax.lax.dot_general(x, r_scr[...],
-                                    (((0,), (0,)), ((), ())))   # (blk_d,1)
-        g_ref[0] = jax.lax.dot_general(xtres, b[None, :],
-                                       (((1,), (0,)), ((), ())))
+def _x_spec(n, blk_d):
+    return pl.BlockSpec((1, n, blk_d), lambda t, *g: (t, 0, g[-1]))
 
 
-def task_grad_tiles(X, U, B, y, *, blk_d: int = 256,
-                    interpret: bool = True):
-    """Per-task gradient contributions, d-tiled:
-    out (T, d, r) with out[t] = X_tᵀ(X_t U b_t − y_t) b_tᵀ.
-    Sum over T outside (ops.py) to get ∇f = Σ_t out[t]."""
-    T, n, d = X.shape
-    r = U.shape[1]
-    blk_d = min(blk_d, d)
-    if d % blk_d:
-        raise ValueError(f"d={d} must be a multiple of blk_d={blk_d} "
-                         f"(ops.py pads)")
-    grid = (T, 2, d // blk_d)
-
-    kernel = functools.partial(_grad_kernel, n=n)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n, blk_d), lambda t, p, i: (t, 0, i)),
-            pl.BlockSpec((blk_d, r), lambda t, p, i: (i, 0)),
-            pl.BlockSpec((1, r), lambda t, p, i: (t, 0)),
-            pl.BlockSpec((1, n), lambda t, p, i: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_d, r), lambda t, p, i: (t, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, d, r), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((n, r), jnp.float32),      # A accumulator
-            pltpu.VMEM((n, 1), jnp.float32),      # residual
-        ],
-        interpret=interpret,
-    )(X, U, B, y)
+def _u_spec(blk_d, r, tpn):
+    return pl.BlockSpec((1, blk_d, r), lambda t, *g: (t // tpn, g[-1], 0))
 
 
-# ----------------------------------------------------------------------
-# fused node-batched engine kernel
-# ----------------------------------------------------------------------
+def _row_spec(m):
+    """(1, 1, m) block of a per-task (N, 1, m) vector."""
+    return pl.BlockSpec((1, 1, m), lambda t, *g: (t, 0, 0))
+
+
+def _tile_spec(blk_d, r):
+    return pl.BlockSpec((1, blk_d, r), lambda t, *g: (t, g[-1], 0))
+
 
 def _chol_solve_unrolled(G, c, r: int):
     """Solve G b = c for SPD G: (r, r) via fully-unrolled Cholesky +
@@ -182,6 +82,21 @@ def _chol_solve_unrolled(G, c, r: int):
     return jnp.stack(b)
 
 
+def _accum_a(x_ref, u_ref, a_scr):
+    x = x_ref[0].astype(jnp.float32)                 # (n, blk_d)
+    u = u_ref[0].astype(jnp.float32)                 # (blk_d, r)
+    a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
+
+
+def _grad_tile(x_ref, r_scr, b_row, g_ref):
+    """g_ref ← X_tileᵀ resid bᵀ for one (blk_d, r) tile."""
+    x = x_ref[0].astype(jnp.float32)                 # (n, blk_d)
+    xtres = jax.lax.dot_general(x, r_scr[...],
+                                (((0,), (0,)), ((), ())))      # (blk_d, 1)
+    g_ref[0] = jax.lax.dot_general(xtres, b_row,
+                                   (((1,), (0,)), ((), ())))
+
+
 def _fused_iter_kernel(x_ref, u_ref, y_ref, b_ref, gt_ref,
                        a_scr, b_scr, r_scr, *, r: int):
     """Grid (L·tpn, 2, d//blk_d).  Pass 0 streams X/U d-tiles and
@@ -197,10 +112,8 @@ def _fused_iter_kernel(x_ref, u_ref, y_ref, b_ref, gt_ref,
         a_scr[...] = jnp.zeros_like(a_scr)
 
     @pl.when(pi == 0)
-    def _accum_a():
-        x = x_ref[0, 0].astype(jnp.float32)          # (n, blk_d)
-        u = u_ref[0].astype(jnp.float32)             # (blk_d, r)
-        a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
+    def _pass0():
+        _accum_a(x_ref, u_ref, a_scr)
 
     @pl.when((pi == 0) & (di == nd - 1))
     def _solve():
@@ -215,15 +128,11 @@ def _fused_iter_kernel(x_ref, u_ref, y_ref, b_ref, gt_ref,
             a, b[:, None], (((1,), (0,)), ((), ())))[:, 0] - y)[:, None]
 
     @pl.when(pi == 1)
-    def _grad_tile():
-        x = x_ref[0, 0].astype(jnp.float32)          # (n, blk_d)
-        xtres = jax.lax.dot_general(x, r_scr[...],
-                                    (((0,), (0,)), ((), ())))   # (blk_d,1)
-        gt_ref[0, 0] = jax.lax.dot_general(xtres, b_scr[...],
-                                           (((1,), (0,)), ((), ())))
+    def _pass1():
+        _grad_tile(x_ref, r_scr, b_scr[...], gt_ref)
 
 
-def node_fused_iter(X, U, y, *, blk_d: int = 256, interpret: bool = True):
+def node_fused_iter(X, U, y, *, blk_d: int, interpret: bool):
     """One fused AltGDmin iteration for all nodes/tasks in one dispatch.
 
     X: (L, tpn, n, d); U: (L, d, r); y: (L, tpn, n) →
@@ -234,30 +143,16 @@ def node_fused_iter(X, U, y, *, blk_d: int = 256, interpret: bool = True):
     blk_d (ops.py pads)."""
     L, tpn, n, d = X.shape
     r = U.shape[2]
-    blk_d = min(blk_d, d)
-    if d % blk_d:
-        raise ValueError(f"d={d} must be a multiple of blk_d={blk_d} "
-                         f"(ops.py pads)")
-    grid = (L * tpn, 2, d // blk_d)
-
-    kernel = functools.partial(_fused_iter_kernel, r=r)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, n, blk_d),
-                         lambda t, p, i: (t // tpn, t % tpn, 0, i)),
-            pl.BlockSpec((1, blk_d, r), lambda t, p, i: (t // tpn, i, 0)),
-            pl.BlockSpec((1, 1, n), lambda t, p, i: (t // tpn, t % tpn, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, r), lambda t, p, i: (t // tpn, t % tpn, 0)),
-            pl.BlockSpec((1, 1, blk_d, r),
-                         lambda t, p, i: (t // tpn, t % tpn, i, 0)),
-        ],
+    N = L * tpn
+    blk_d = _check_blk(d, blk_d)
+    B, tiles = pl.pallas_call(
+        functools.partial(_fused_iter_kernel, r=r),
+        grid=(N, 2, d // blk_d),
+        in_specs=[_x_spec(n, blk_d), _u_spec(blk_d, r, tpn), _row_spec(n)],
+        out_specs=[_row_spec(r), _tile_spec(blk_d, r)],
         out_shape=[
-            jax.ShapeDtypeStruct((L, tpn, r), jnp.float32),
-            jax.ShapeDtypeStruct((L, tpn, d, r), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1, r), jnp.float32),
+            jax.ShapeDtypeStruct((N, d, r), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((n, r), jnp.float32),      # A accumulator
@@ -265,11 +160,11 @@ def node_fused_iter(X, U, y, *, blk_d: int = 256, interpret: bool = True):
             pltpu.VMEM((n, 1), jnp.float32),      # residual
         ],
         interpret=interpret,
-    )(X, U, y)
+    )(X.reshape(N, n, d), U, y.reshape(N, 1, n))
+    return B.reshape(L, tpn, r), tiles.reshape(L, tpn, d, r)
 
 
-def _gram_kernel_nb(x_ref, u_ref, y_ref, g_ref, c_ref, a_scr):
-    """Node-batched _gram_kernel (rank-4 blocks, per-node U tile)."""
+def _gram_kernel(x_ref, u_ref, y_ref, g_ref, c_ref, a_scr):
     di = pl.program_id(1)
     nd = pl.num_programs(1)
 
@@ -277,68 +172,55 @@ def _gram_kernel_nb(x_ref, u_ref, y_ref, g_ref, c_ref, a_scr):
     def _init():
         a_scr[...] = jnp.zeros_like(a_scr)
 
-    x = x_ref[0, 0].astype(jnp.float32)              # (n, blk_d)
-    u = u_ref[0].astype(jnp.float32)                 # (blk_d, r)
-    a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
+    _accum_a(x_ref, u_ref, a_scr)
 
     @pl.when(di == nd - 1)
     def _finalize():
         a = a_scr[...]                               # (n, r)
         y = y_ref[0, 0].astype(jnp.float32)          # (n,)
-        g_ref[0, 0] = jax.lax.dot_general(a, a, (((0,), (0,)), ((), ())))
+        g_ref[0] = jax.lax.dot_general(a, a, (((0,), (0,)), ((), ())))
         c_ref[0, 0] = jax.lax.dot_general(y[None, :], a,
                                           (((1,), (0,)), ((), ())))[0]
 
 
-def node_task_gram(X, U, y, *, blk_d: int = 256, interpret: bool = True):
+def node_task_gram(X, U, y, *, blk_d: int, interpret: bool):
     """Node-batched Gram systems (min-B half only — the sample-split path
-    where min and gradient use different folds).
+    where min and gradient use different folds, and the serving solve).
     X: (L, tpn, n, d); U: (L, d, r); y: (L, tpn, n) →
     (G (L, tpn, r, r), c (L, tpn, r))."""
     L, tpn, n, d = X.shape
     r = U.shape[2]
-    blk_d = min(blk_d, d)
-    if d % blk_d:
-        raise ValueError(f"d={d} must be a multiple of blk_d={blk_d} "
-                         f"(ops.py pads)")
-    grid = (L * tpn, d // blk_d)
-
-    return pl.pallas_call(
-        _gram_kernel_nb,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, n, blk_d),
-                         lambda t, i: (t // tpn, t % tpn, 0, i)),
-            pl.BlockSpec((1, blk_d, r), lambda t, i: (t // tpn, i, 0)),
-            pl.BlockSpec((1, 1, n), lambda t, i: (t // tpn, t % tpn, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, r, r), lambda t, i: (t // tpn, t % tpn, 0, 0)),
-            pl.BlockSpec((1, 1, r), lambda t, i: (t // tpn, t % tpn, 0)),
-        ],
+    N = L * tpn
+    blk_d = _check_blk(d, blk_d)
+    G, c = pl.pallas_call(
+        _gram_kernel,
+        grid=(N, d // blk_d),
+        in_specs=[_x_spec(n, blk_d), _u_spec(blk_d, r, tpn), _row_spec(n)],
+        out_specs=[pl.BlockSpec((1, r, r), lambda t, i: (t, 0, 0)),
+                   _row_spec(r)],
         out_shape=[
-            jax.ShapeDtypeStruct((L, tpn, r, r), jnp.float32),
-            jax.ShapeDtypeStruct((L, tpn, r), jnp.float32),
+            jax.ShapeDtypeStruct((N, r, r), jnp.float32),
+            jax.ShapeDtypeStruct((N, 1, r), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, r), jnp.float32)],
         interpret=interpret,
-    )(X, U, y)
+    )(X.reshape(N, n, d), U, y.reshape(N, 1, n))
+    return G.reshape(L, tpn, r, r), c.reshape(L, tpn, r)
 
 
-def _grad_kernel_nb(x_ref, u_ref, b_ref, y_ref, g_ref, a_scr, r_scr):
-    """Node-batched _grad_kernel (rank-4 blocks, per-node U tile)."""
+def _grad_kernel(x_ref, u_ref, b_ref, y_ref, g_ref, a_scr, r_scr):
+    """Two passes over d per task (grid dims: task, pass, d-tile):
+    pass 0 accumulates A = X U; pass 1 computes resid = A b − y once, then
+    writes the disjoint (blk_d, r) gradient tiles X_tileᵀ resid bᵀ."""
     pi, di = pl.program_id(1), pl.program_id(2)
-    nd = pl.num_programs(2)
 
     @pl.when((pi == 0) & (di == 0))
     def _init():
         a_scr[...] = jnp.zeros_like(a_scr)
 
     @pl.when(pi == 0)
-    def _accum_a():
-        x = x_ref[0, 0].astype(jnp.float32)
-        u = u_ref[0].astype(jnp.float32)
-        a_scr[...] += jax.lax.dot_general(x, u, (((1,), (0,)), ((), ())))
+    def _pass0():
+        _accum_a(x_ref, u_ref, a_scr)
 
     @pl.when((pi == 1) & (di == 0))
     def _resid():
@@ -349,45 +231,30 @@ def _grad_kernel_nb(x_ref, u_ref, b_ref, y_ref, g_ref, a_scr, r_scr):
             - y)[:, None]                            # (n, 1)
 
     @pl.when(pi == 1)
-    def _grad_tile():
-        x = x_ref[0, 0].astype(jnp.float32)          # (n, blk_d)
-        b = b_ref[0, 0].astype(jnp.float32)          # (r,)
-        xtres = jax.lax.dot_general(x, r_scr[...],
-                                    (((0,), (0,)), ((), ())))   # (blk_d,1)
-        g_ref[0, 0] = jax.lax.dot_general(xtres, b[None, :],
-                                          (((1,), (0,)), ((), ())))
+    def _pass1():
+        _grad_tile(x_ref, r_scr, b_ref[0].astype(jnp.float32), g_ref)
 
 
-def node_task_grad_tiles(X, U, B, y, *, blk_d: int = 256,
-                         interpret: bool = True):
+def node_task_grad_tiles(X, U, B, y, *, blk_d: int, interpret: bool):
     """Node-batched gradient tiles with a given B (sample-split path —
     A must be rebuilt on the gradient fold's data, so this keeps the
     two-pass structure).  X: (L, tpn, n, d); U: (L, d, r); B: (L, tpn, r);
     y: (L, tpn, n) → (L, tpn, d, r)."""
     L, tpn, n, d = X.shape
     r = U.shape[2]
-    blk_d = min(blk_d, d)
-    if d % blk_d:
-        raise ValueError(f"d={d} must be a multiple of blk_d={blk_d} "
-                         f"(ops.py pads)")
-    grid = (L * tpn, 2, d // blk_d)
-
-    return pl.pallas_call(
-        _grad_kernel_nb,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, n, blk_d),
-                         lambda t, p, i: (t // tpn, t % tpn, 0, i)),
-            pl.BlockSpec((1, blk_d, r), lambda t, p, i: (t // tpn, i, 0)),
-            pl.BlockSpec((1, 1, r), lambda t, p, i: (t // tpn, t % tpn, 0)),
-            pl.BlockSpec((1, 1, n), lambda t, p, i: (t // tpn, t % tpn, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, blk_d, r),
-                               lambda t, p, i: (t // tpn, t % tpn, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((L, tpn, d, r), jnp.float32),
+    N = L * tpn
+    blk_d = _check_blk(d, blk_d)
+    tiles = pl.pallas_call(
+        _grad_kernel,
+        grid=(N, 2, d // blk_d),
+        in_specs=[_x_spec(n, blk_d), _u_spec(blk_d, r, tpn), _row_spec(r),
+                  _row_spec(n)],
+        out_specs=_tile_spec(blk_d, r),
+        out_shape=jax.ShapeDtypeStruct((N, d, r), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((n, r), jnp.float32),      # A accumulator
             pltpu.VMEM((n, 1), jnp.float32),      # residual
         ],
         interpret=interpret,
-    )(X, U, B, y)
+    )(X.reshape(N, n, d), U, B.reshape(N, 1, r), y.reshape(N, 1, n))
+    return tiles.reshape(L, tpn, d, r)

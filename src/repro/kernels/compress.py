@@ -36,41 +36,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _topk_kernel(m_ref, vals_ref, idx_ref, *, k: int):
-    m = m_ref[0]                                        # (d, r)
-    s = jnp.sum(m.astype(jnp.float32) ** 2, axis=1)     # (d,) row norms
+def _topk_kernel(m_ref, vals_ref, idx_ref, *, k: int, d: int):
+    m = m_ref[0].astype(jnp.float32)                    # (d, r)
+    s = jnp.sum(m * m, axis=1, keepdims=True)           # (d, 1) row norms
+    rows = jax.lax.broadcasted_iota(jnp.int32, (d, 1), 0)
 
     def select(j, s):
-        i0 = jnp.argmax(s).astype(jnp.int32)            # first max (stable)
-        row = jax.lax.dynamic_index_in_dim(m, i0, axis=0, keepdims=True)
-        pl.store(vals_ref, (pl.ds(0, 1), pl.ds(j, 1), slice(None)),
-                 row[None])
-        pl.store(idx_ref, (pl.ds(0, 1), pl.ds(j, 1)), i0[None, None])
-        return s.at[i0].set(-jnp.inf)
+        top = jnp.max(s)
+        i0 = jnp.min(jnp.where(s == top, rows, d))      # first max (stable)
+        vals_ref[0, pl.ds(j, 1), :] = m_ref[0, pl.ds(i0, 1), :]
+        idx_ref[0, pl.ds(j, 1), :] = jnp.full((1, 1), i0, jnp.int32)
+        return jnp.where(rows == i0, -jnp.inf, s)
 
     jax.lax.fori_loop(0, k, select, s)
 
 
-def compress_topk(M, k: int, *, interpret: bool = True):
+def compress_topk(M, k: int, *, interpret: bool):
     """Top-k row sparsification.  M: (N, d, r) → (vals (N, k, r) in
     M.dtype, descending row-norm order; idx (N, k) int32).  One grid
     cell per node block; d×r is small (the subspace iterate), so the
     whole block sits in VMEM.  Ties between equal row norms resolve to
-    the lowest index (matching ``lax.top_k``'s stable order)."""
+    the lowest index (matching ``lax.top_k``'s stable order).  The
+    kernel writes idx as (N, k, 1) so its block's last two dims are the
+    array's own."""
     N, d, r = M.shape
     if not 1 <= k <= d:
         raise ValueError(f"compress_topk needs 1 <= k <= d, got k={k}, "
                          f"d={d}")
-    return pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
+    vals, idx = pl.pallas_call(
+        functools.partial(_topk_kernel, k=k, d=d),
         grid=(N,),
         in_specs=[pl.BlockSpec((1, d, r), lambda i: (i, 0, 0))],
         out_specs=(pl.BlockSpec((1, k, r), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))),
+                   pl.BlockSpec((1, k, 1), lambda i: (i, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct((N, k, r), M.dtype),
-                   jax.ShapeDtypeStruct((N, k), jnp.int32)),
+                   jax.ShapeDtypeStruct((N, k, 1), jnp.int32)),
         interpret=interpret,
     )(M)
+    return vals, idx.reshape(N, k)
 
 
 def _dequant_kernel(scale_ref, q_ref, o_ref):
@@ -78,7 +81,7 @@ def _dequant_kernel(scale_ref, q_ref, o_ref):
     o_ref[...] = (q_ref[...].astype(jnp.float32) * s).astype(o_ref.dtype)
 
 
-def dequant(q, scale, *, interpret: bool = True):
+def dequant(q, scale, *, interpret: bool):
     """Decode an int8 wire payload: ``q · scale`` per node block with f32
     accumulation.  q: (N, d, r) int8; scale: (N, 1, 1) → (N, d, r) in
     scale.dtype."""

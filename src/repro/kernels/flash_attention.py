@@ -87,7 +87,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     scale=None, blk_q: int = 128, blk_k: int = 128,
-                    offset: int | None = None, interpret: bool = True):
+                    offset: int | None = None, interpret: bool):
     """q: (B,H,Sq,D); k,v: (B,Hkv,Skv,D) → (B,H,Sq,D).
 
     Sq and Skv must be multiples of the block sizes (ops.py pads).

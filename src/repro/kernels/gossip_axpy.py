@@ -10,16 +10,18 @@ same ONE dispatch per round as the uniform ring.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _combine_kernel(w_ref, z_ref, nbr_ref, o_ref):
-    w = w_ref[...].astype(jnp.float32)               # (K+1, 1)
-    z = z_ref[...].astype(jnp.float32)               # (blk, C)
-    nbr = nbr_ref[...].astype(jnp.float32)           # (K, blk, C)
-    acc = w[0, 0] * z + jnp.sum(w[1:, :, None] * nbr, axis=0)
+def _combine_kernel(w_ref, z_ref, nbr_ref, o_ref, *, K: int):
+    acc = w_ref[0] * z_ref[...].astype(jnp.float32)          # (blk, C)
+    for k in range(K):                                       # static unroll
+        acc = acc + w_ref[k + 1] * nbr_ref[k].astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -30,7 +32,7 @@ def _mix_kernel(w_ref, z_ref, o_ref):
         w, z, (((1,), (0,)), ((), ()))).astype(o_ref.dtype)
 
 
-def mix_rows(W, Z, *, blk_c: int = 512, interpret: bool = True):
+def mix_rows(W, Z, *, blk_c: int, interpret: bool):
     """Fused consensus combine Z ← W Z for a precomputed mixing matrix
     (typically W^{T_con} from ``agree_power`` — the whole AGREE phase in
     ONE weighted combine instead of T_con HBM sweeps).  The node count L
@@ -55,8 +57,8 @@ def mix_rows(W, Z, *, blk_c: int = 512, interpret: bool = True):
     )(W, Z)
 
 
-def gossip_combine(z, neighbors, weights, *, blk_rows: int = 256,
-                   interpret: bool = True):
+def gossip_combine(z, neighbors, weights, *, blk_rows: int,
+                   interpret: bool):
     """z: (M, C); neighbors: (K, M, C); weights: (K+1,) → (M, C).
 
     Row counts not divisible by ``blk_rows`` are zero-padded and trimmed
@@ -69,12 +71,12 @@ def gossip_combine(z, neighbors, weights, *, blk_rows: int = 256,
         z = jnp.pad(z, ((0, pad), (0, 0)))
         neighbors = jnp.pad(neighbors, ((0, 0), (0, pad), (0, 0)))
     Mp = M + pad
-    w = jnp.asarray(weights, jnp.float32).reshape(K + 1, 1)
+    w = jnp.asarray(weights, jnp.float32).reshape(K + 1)
     out = pl.pallas_call(
-        _combine_kernel,
+        functools.partial(_combine_kernel, K=K),
         grid=(Mp // blk_rows,),
         in_specs=[
-            pl.BlockSpec((K + 1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((blk_rows, C), lambda i: (i, 0)),
             pl.BlockSpec((K, blk_rows, C), lambda i: (0, i, 0)),
         ],

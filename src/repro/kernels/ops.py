@@ -95,7 +95,14 @@ def resolve_backend(backend: str | None) -> str:
 
 def _interp(backend: str) -> bool:
     """interpret flag for the two Pallas backends (callers must have
-    routed xla-ref elsewhere already)."""
+    routed xla-ref elsewhere already).  The compiled kernels lower for
+    the TPU only with x64 off: Mosaic takes int32 grid ids and index
+    maps, and under x64 their constants are int64."""
+    if backend == "pallas" and jax.config.jax_enable_x64:
+        raise ValueError("backend 'pallas' needs jax_enable_x64 off; run "
+                         "the compiled kernels in float32 (x64 is the "
+                         "CPU oracle's mode: use 'pallas-interpret' or "
+                         "'xla-ref' there)")
     return backend != "pallas"
 
 
@@ -190,39 +197,18 @@ def _pad_d(X, U, blk_d):
 
 
 def altgdmin_minimize_B(X, U, y, *, blk_d=256, backend=None):
-    """b_t = (X_t U)† y_t via kernel Gram + tiny jnp Cholesky solve.
-    X: (T,n,d); U: (d,r); y: (T,n) → B (T,r)."""
-    return _altgdmin_minimize_B(X, U, y, blk_d=blk_d,
-                                backend=resolve_backend(backend))
-
-
-@functools.partial(jax.jit, static_argnames=("blk_d", "backend"))
-def _altgdmin_minimize_B(X, U, y, *, blk_d, backend):
-    if backend == "xla-ref":
-        G, c = _ref.ref_task_gram(X, U, y)
-    else:
-        Xp, Up, blk = _pad_d(X, U, blk_d)
-        G, c = _ls.task_gram(Xp, Up, y, blk_d=blk,
-                             interpret=_interp(backend))
-    return jax.vmap(_solve_spd)(G, c)
+    """b_t = (X_t U)† y_t for one node's tasks (the node-batched kernel
+    with L = 1).  X: (T,n,d); U: (d,r); y: (T,n) → B (T,r)."""
+    return altgdmin_node_minimize_B(X[None], U[None], y[None], blk_d=blk_d,
+                                    backend=backend)[0]
 
 
 def altgdmin_gradient(X, U, B, y, *, blk_d=256, backend=None):
-    """∇_U f = Σ_t X_tᵀ(X_t U b_t − y_t) b_tᵀ via the fused two-pass
-    kernel. X: (T,n,d); U: (d,r); B: (T,r); y: (T,n) → (d,r)."""
-    return _altgdmin_gradient(X, U, B, y, blk_d=blk_d,
-                              backend=resolve_backend(backend))
-
-
-@functools.partial(jax.jit, static_argnames=("blk_d", "backend"))
-def _altgdmin_gradient(X, U, B, y, *, blk_d, backend):
-    if backend == "xla-ref":
-        return _ref.ref_altgdmin_grad(X, U, B, y)
-    d = X.shape[2]
-    Xp, Up, blk = _pad_d(X, U, blk_d)
-    tiles = _ls.task_grad_tiles(Xp, Up, B, y, blk_d=blk,
-                                interpret=_interp(backend))
-    return jnp.sum(tiles, axis=0)[:d]
+    """∇_U f = Σ_t X_tᵀ(X_t U b_t − y_t) b_tᵀ for one node (the
+    node-batched kernel with L = 1).  X: (T,n,d); U: (d,r); B: (T,r);
+    y: (T,n) → (d,r)."""
+    return altgdmin_node_gradient(X[None], U[None], B[None], y[None],
+                                  blk_d=blk_d, backend=backend)[0]
 
 
 # ---------------------------------------------- MTRL LS (node-batched)

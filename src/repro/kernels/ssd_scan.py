@@ -70,7 +70,7 @@ def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, y_ref, h_ref,
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool):
     """x: (B,H,S,P); dt: (B,H,S); A: (H,); Bm/Cm: (B,S,N); D: (H,) →
     (y (B,H,S,P), h_final (B,H,P,N)).  S must be a multiple of ``chunk``
     (ops.py pads)."""

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils.compat import make_mesh
 
 # TPU v5e hardware constants (roofline denominators; EXPERIMENTS.md §Roofline)
 PEAK_FLOPS_BF16 = 197e12        # per chip
@@ -23,7 +22,8 @@ ICI_BW = 50e9                   # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def node_axes(mesh) -> tuple:
@@ -49,4 +49,5 @@ def n_chips(mesh) -> int:
 def make_host_mesh():
     """Whatever devices exist locally (tests / examples): 1-D data mesh."""
     n = len(jax.devices())
-    return make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

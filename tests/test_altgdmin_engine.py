@@ -178,13 +178,16 @@ def test_fused_kernel_builds_A_exactly_once():
                                      backend="xla-ref")
 
     fused = _count_a_builds(
-        lambda X, U, y: ls.node_fused_iter(X, U, y, blk_d=blk),
+        lambda X, U, y: ls.node_fused_iter(X, U, y, blk_d=blk,
+                                           interpret=True),
         X, U, y, n=n, blk_d=blk, r=r)
     gram = _count_a_builds(
-        lambda X, U, y: ls.node_task_gram(X, U, y, blk_d=blk),
+        lambda X, U, y: ls.node_task_gram(X, U, y, blk_d=blk,
+                                          interpret=True),
         X, U, y, n=n, blk_d=blk, r=r)
     grad = _count_a_builds(
-        lambda X, U, B, y: ls.node_task_grad_tiles(X, U, B, y, blk_d=blk),
+        lambda X, U, B, y: ls.node_task_grad_tiles(X, U, B, y, blk_d=blk,
+                                                   interpret=True),
         X, U, B, y, n=n, blk_d=blk, r=r)
 
     assert fused == 1, f"fused kernel builds A {fused}× per task"

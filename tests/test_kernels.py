@@ -136,11 +136,13 @@ def test_task_gram_and_minimize_B(T, n, d, r, blk_d):
     np.testing.assert_allclose(np.asarray(B), np.asarray(B_ref), rtol=1e-3,
                                atol=1e-4)
     # Gram pieces vs oracle
-    from repro.kernels.altgdmin_ls import task_gram
+    from repro.kernels.altgdmin_ls import node_task_gram
     dpad = (-d) % blk_d
     Xp = jnp.pad(X, ((0, 0), (0, 0), (0, dpad)))
     Up = jnp.pad(U, ((0, dpad), (0, 0)))
-    G, c = task_gram(Xp, Up, y, blk_d=min(blk_d, d + dpad))
+    G, c = node_task_gram(Xp[None], Up[None], y[None],
+                          blk_d=min(blk_d, d + dpad), interpret=True)
+    G, c = G[0], c[0]
     G_ref, c_ref = ref.ref_task_gram(X, U, y)
     np.testing.assert_allclose(np.asarray(G), np.asarray(G_ref), rtol=1e-4,
                                atol=1e-4)
@@ -223,7 +225,8 @@ def test_gossip_combine_kernel_odd_rows():
     nbrs = jax.random.normal(jax.random.fold_in(key, 1), (2, 300, 8),
                              jnp.float32)
     weights = jnp.asarray([0.5, 0.3, 0.2], jnp.float32)
-    out = gossip_axpy.gossip_combine(z, nbrs, weights, interpret=True)
+    out = gossip_axpy.gossip_combine(z, nbrs, weights, blk_rows=256,
+                                      interpret=True)
     want = ref.ref_gossip_combine(z, nbrs, weights)
     assert out.shape == z.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),

@@ -184,7 +184,6 @@ _PRELUDE = """
     from repro.api.registry import get_solver
     from repro.distributed import graphs, mixing
     from repro.distributed import consensus as cons
-    from repro.utils.compat import make_mesh
 
     SPEC_KW = {
         "beyond_central": dict(local_steps=2),
@@ -222,7 +221,8 @@ _PRELUDE = """
 
 MESH_SCRIPT = textwrap.dedent(_PRELUDE + """
     prob, Xg, yg, adj, W, U0, eta, avail = setup(8, 0.6, 2)
-    mesh = make_mesh((8,), ("nodes",))
+    mesh = jax.make_mesh((8,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     Madj = np.asarray(cons.neighbor_average_matrix(adj))
     fails = []
     for name in NAMES:
@@ -249,7 +249,8 @@ MESH_SCRIPT = textwrap.dedent(_PRELUDE + """
 VIRTUAL_SCRIPT = textwrap.dedent(_PRELUDE + """
     from repro.distributed.mixing import SparseWeights
     prob, Xg, yg, adj, W, U0, eta, avail = setup(16, 0.4, 3)
-    mesh = make_mesh((8,), ("nodes",))
+    mesh = jax.make_mesh((8,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     vtW = cons.VirtualTopology.from_weights(
         SparseWeights.from_dense(np.asarray(W)), 8)
     Madj = np.asarray(cons.neighbor_average_matrix(adj))

@@ -40,8 +40,8 @@ SCRIPT = textwrap.dedent("""
     sim = dif_altgdmin(init.U0, Xg, yg, W, eta=eta, T_GD=150, T_con=2,
                        U_star=prob.U_star)
 
-    from repro.utils.compat import make_mesh
-    mesh = make_mesh((L,), ("nodes",))
+    mesh = jax.make_mesh((L,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     U_hw, B_hw = dif_altgdmin_mesh(init.U0, Xg, yg, mesh, "nodes",
                                    eta=eta, T_GD=150, T_con=2)
 
@@ -208,7 +208,6 @@ FUSED_COMBINE_SCRIPT = textwrap.dedent("""
         decentralized_spectral_init
     from repro.core import dif_altgdmin_mesh
     from repro.distributed import circulant_weights
-    from repro.utils.compat import make_mesh
     from repro.kernels import ops
 
     # count trace-time gossip_combine dispatches: the round body of the
@@ -229,7 +228,8 @@ FUSED_COMBINE_SCRIPT = textwrap.dedent("""
     init = decentralized_spectral_init(
         jax.random.PRNGKey(1), Xg, yg, W, kappa=prob.kappa, mu=prob.mu,
         r=prob.r, T_pm=10, T_con=4)
-    mesh = make_mesh((L,), ("nodes",))
+    mesh = jax.make_mesh((L,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     U, B = dif_altgdmin_mesh(init.U0, Xg, yg, mesh, "nodes", eta=1e-4,
                              T_GD=4, T_con=T_con,
                              backend="pallas-interpret")
@@ -339,7 +339,6 @@ WEIGHTED_COMBINE_SCRIPT = textwrap.dedent("""
         decentralized_spectral_init
     from repro.core import dif_altgdmin_mesh
     from repro.distributed import erdos_renyi, metropolis_weights
-    from repro.utils.compat import make_mesh
     from repro.kernels import ops
 
     # weighted combines must stay ONE fused dispatch per gossip round:
@@ -362,7 +361,8 @@ WEIGHTED_COMBINE_SCRIPT = textwrap.dedent("""
     init = decentralized_spectral_init(
         jax.random.PRNGKey(1), Xg, yg, W, kappa=prob.kappa, mu=prob.mu,
         r=prob.r, T_pm=10, T_con=4)
-    mesh = make_mesh((L,), ("nodes",))
+    mesh = jax.make_mesh((L,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     U, B = dif_altgdmin_mesh(init.U0, Xg, yg, mesh, "nodes", eta=1e-4,
                              T_GD=4, T_con=T_con, W=np.asarray(W),
                              backend="pallas-interpret")
@@ -468,7 +468,6 @@ COMPRESSED_COMBINE_SCRIPT = textwrap.dedent("""
         decentralized_spectral_init
     from repro.core import dif_topk_mesh
     from repro.distributed import circulant_weights
-    from repro.utils.compat import make_mesh
     from repro.kernels import ops
     from repro.kernels import compress as cpk
 
@@ -495,7 +494,8 @@ COMPRESSED_COMBINE_SCRIPT = textwrap.dedent("""
     init = decentralized_spectral_init(
         jax.random.PRNGKey(1), Xg, yg, W, kappa=prob.kappa, mu=prob.mu,
         r=prob.r, T_pm=10, T_con=4)
-    mesh = make_mesh((L,), ("nodes",))
+    mesh = jax.make_mesh((L,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     U, B = dif_topk_mesh(init.U0, Xg, yg, mesh, "nodes", eta=1e-4,
                          T_GD=4, T_con=T_con, compression_k=8,
                          backend="pallas-interpret")

@@ -19,8 +19,8 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.specs import input_specs
     from repro.utils.hlo import collective_stats
 
-    from repro.utils.compat import make_mesh
-    mesh = make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("mamba2-130m")
 
     def lower(agg):
