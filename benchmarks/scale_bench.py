@@ -152,7 +152,7 @@ def bench_rcm(quick: bool = False):
     return rows
 
 
-def virtual_mesh_rows(L: int):
+def virtual_mesh_rows(L: int, dtype: str = "float64"):
     """The virtual_mesh rows on whatever devices this process sees."""
     import dataclasses
     from repro.api.runner import materialize, run_experiment
@@ -160,7 +160,8 @@ def virtual_mesh_rows(L: int):
                                 SolverSpec, TopologySpec)
 
     base = ExperimentSpec(
-        problem=ProblemSpec(d=16, T=L, r=2, n=8, L=L, kappa=1.2),
+        problem=ProblemSpec(d=16, T=L, r=2, n=8, L=L, kappa=1.2,
+                            dtype=dtype),
         topology=TopologySpec(family="barabasi_albert", ba_m=3, seed=0,
                               weights="metropolis",
                               representation="sparse"),
@@ -215,13 +216,15 @@ _VIRTUAL_SCRIPT = textwrap.dedent("""
 def bench_virtual_mesh(quick: bool = False):
     """Virtual-node mesh tier rows — non-gossip programs at large L.  On
     a TPU host they run in this process over its chips (a child could
-    not reach a chip this process holds); elsewhere on 8 fake host
-    devices in a child process."""
+    not reach a chip this process holds), in float32 with x64 off as the
+    compiled kernels need; elsewhere in float64 on 8 fake host devices in
+    a child process."""
     import os
 
     L = 10_000 if quick else 100_000
     if jax.default_backend() == "tpu":
-        return virtual_mesh_rows(L)
+        with jax.enable_x64(False):
+            return virtual_mesh_rows(L, dtype="float32")
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     r = subprocess.run([sys.executable, "-c", _VIRTUAL_SCRIPT, str(L)],

@@ -159,7 +159,7 @@ def main(argv=None) -> None:
     p = sub.add_parser("run", help="execute a grid, one process per cell")
     p.add_argument("--specs", required=True, help="JSON grid from emit")
     p.add_argument("--out", required=True, help="merged CSV path")
-    p.add_argument("--jobs", type=int, default=1,
+    p.add_argument("--jobs", type=int, default=2,
                    help="cells run at once (CPU only: >1 is refused on TPU)")
     p.add_argument("--in-process", action="store_true",
                    help="run cells in this process (tests / debugging)")

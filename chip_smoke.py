@@ -26,7 +26,7 @@ The last line of stdout is one JSON object,
 A failed check, a platform other than TPU, or an exception exits
 non-zero without it.
 
-    python3 chip_smoke.py [--chips 4] [--iters 40] [--seed 0]
+    python3 chip_smoke.py [--chips 4]
 """
 from __future__ import annotations
 
@@ -46,6 +46,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 TOL_U = 1e-4          # max |U_a − U_b| over entries of orthonormal bases
 TOL_SERVE = 1e-4      # max |θ_a − θ_b| / max |θ_b| over served requests
 TOL_KERNEL = 1e-5     # max |kernel − oracle| / max |oracle|
+T_GD = 40             # outer iterations per training run
+SEED = 0              # problem, init and request seed
 
 
 class Checks:
@@ -60,7 +62,7 @@ class Checks:
             self.failed.append(name)
 
 
-def experiment1_spec(*, T_GD: int, backend: str, L: int | None = None,
+def experiment1_spec(*, backend: str, L: int | None = None,
                      substrate: str = "simulator"):
     from repro.api import (EngineSpec, ExperimentSpec, InitSpec, ProblemSpec,
                            SolverSpec, TopologySpec)
@@ -85,46 +87,44 @@ def _max_abs(a, b) -> float:
                                - np.asarray(b, np.float64))))
 
 
-def _timed_run(spec, mat, seed):
+def _timed_run(spec, mat):
     import jax
     from repro.api import run_experiment
     t0 = time.perf_counter()
-    trace = run_experiment(spec, key=seed, materialized=mat)
+    trace = run_experiment(spec, key=SEED, materialized=mat)
     jax.block_until_ready(trace.U_nodes)
     return trace, time.perf_counter() - t0
 
 
 def compiled_kernel_count(spec, mat) -> tuple[int, int]:
     """(Pallas kernels in the compiled run of ONE outer iteration plus
-    the final B refit, the program's per-iteration dispatch budget)."""
+    the final B refit, the program's per-iteration dispatch budget).
+    The iteration is the runner's own simulator call (``simulate``), so
+    its engine and η are resolved as in the counted run."""
     import jax
-    from repro.api import get_solver
-    from repro.core.engine import AltgdminEngine
-    solver = get_solver(spec.solver.name)
-    eng = AltgdminEngine(spec.engine.backend, blk_d=spec.engine.blk_d)
-    T_con = spec.solver.T_con
+    from repro.api import get_solver, simulate
 
-    def one_iter(U0, Xg, yg, U_star):
-        res = solver.call(U0, Xg, yg, mat.W, mat.adj, eta=mat.eta, T_GD=1,
-                          T_con=T_con, U_star=U_star, engine=eng)
+    def one_iter(Xg, yg):
+        res = simulate(spec, dataclasses.replace(mat, Xg=Xg, yg=yg),
+                       T_GD=1)
         return res.U_nodes, res.B_nodes
 
-    hlo = jax.jit(one_iter).lower(mat.init.U0, mat.Xg, mat.yg,
-                                  mat.problem.U_star).compile().as_text()
+    hlo = jax.jit(one_iter).lower(mat.Xg, mat.yg).compile().as_text()
     n = hlo.count('custom_call_target="tpu_custom_call"')
+    solver = get_solver(spec.solver.name)
     budget = solver.dispatch_budget.per_iter(
-        "simulator", solver.signature(T_con).rounds_per_iter, 0, 1)
+        "simulator", solver.signature(spec.solver.T_con).rounds_per_iter,
+        0, 1)
     return n, budget
 
 
-def kernel_phase(check: Checks, *, backend: str, seed: int,
-                 N: int = 20, d: int = 600, r: int = 4, k: int = 150,
-                 K: int = 3) -> None:
+def kernel_phase(check: Checks, *, backend: str, N: int = 20, d: int = 600,
+                 r: int = 4, k: int = 150, K: int = 3) -> None:
     """The compressed-wire and gossip kernels against their oracles."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops
-    key = jax.random.PRNGKey(seed)
+    key = jax.random.PRNGKey(SEED)
     M = jax.random.normal(key, (N, d, r), jnp.float32)
     v, i = ops.compress_topk(M, k, backend=backend)
     v_ref, i_ref = ops.compress_topk(M, k, backend="xla-ref")
@@ -153,25 +153,25 @@ def kernel_phase(check: Checks, *, backend: str, seed: int,
 
 
 def train_phase(check: Checks, *, backend: str, ref_backend: str,
-                T_GD: int, seed: int, count_kernels: bool, spec=None):
+                count_kernels: bool):
     """Dif-AltGDmin through run_experiment on ``backend`` and
     ``ref_backend``; returns the fused run's Trace."""
     import jax
     import numpy as np
     from repro.api import materialize
     from repro.core.metrics import subspace_distance
-    spec = spec or experiment1_spec(T_GD=T_GD, backend=backend)
+    spec = experiment1_spec(backend=backend)
     p = spec.problem
     print(f"training: dif_altgdmin L={p.L} d={p.d} T={p.T} r={p.r} "
           f"n={p.n} T_GD={spec.solver.T_GD} T_con={spec.solver.T_con} "
           f"{p.dtype}", flush=True)
-    mat = materialize(spec, key=seed)
+    mat = materialize(spec, key=SEED)
     sd_init = float(jax.vmap(lambda u: subspace_distance(
         u, mat.problem.U_star))(mat.init.U0).max())
-    fused, t_fused = _timed_run(spec, mat, seed)
+    fused, t_fused = _timed_run(spec, mat)
     ref, t_ref = _timed_run(dataclasses.replace(
         spec, engine=dataclasses.replace(spec.engine, backend=ref_backend)),
-        mat, seed)
+        mat)
     print(f"  {backend}: {t_fused:.2f} s wall incl. compile; "
           f"{ref_backend}: {t_ref:.2f} s wall incl. compile", flush=True)
     sd = np.asarray(fused.sd_max)
@@ -191,7 +191,7 @@ def train_phase(check: Checks, *, backend: str, ref_backend: str,
 
 
 def serve_phase(check: Checks, trace, U_star, *, backend: str,
-                ref_backend: str, seed: int, n_requests: int = 24,
+                ref_backend: str, n_requests: int = 24,
                 max_batch: int = 32, t_new=(6, 10, 16, 30)) -> None:
     """Ragged personalization requests on the trained basis."""
     import numpy as np
@@ -200,7 +200,7 @@ def serve_phase(check: Checks, trace, U_star, *, backend: str,
     from repro.serving.queue import RequestGenerator
     U = deployable_basis(trace.U_nodes)
     reqs = RequestGenerator(np.asarray(U_star), t_new=t_new,
-                            seed=seed).generate(n_requests)
+                            seed=SEED).generate(n_requests)
     X = [q.X for q in reqs]
     y = [q.y for q in reqs]
     theta_star = np.stack([q.theta_star for q in reqs])
@@ -223,21 +223,19 @@ def serve_phase(check: Checks, trace, U_star, *, backend: str,
           f"max |Δθ| / max |θ| = {rel:.3e} (tol {TOL_SERVE:g})")
 
 
-def mesh_phase(check: Checks, *, backend: str, T_GD: int, seed: int,
-               n_dev: int) -> None:
+def mesh_phase(check: Checks, *, backend: str, n_dev: int) -> None:
     """Mesh substrate (one node per device) and virtual tier (five
     nodes per device), each against the simulator run of the spec."""
     from repro.api import materialize
     for L, tier in ((n_dev, "mesh"), (5 * n_dev, "virtual")):
-        spec = experiment1_spec(T_GD=T_GD, backend=backend, L=L,
-                                substrate="mesh")
+        spec = experiment1_spec(backend=backend, L=L, substrate="mesh")
         print(f"{tier}: dif_altgdmin L={L} on {n_dev} devices, "
               f"T_GD={T_GD}, {backend}", flush=True)
-        mat = materialize(spec, key=seed)
-        hw, t_hw = _timed_run(spec, mat, seed)
+        mat = materialize(spec, key=SEED)
+        hw, t_hw = _timed_run(spec, mat)
         sim, t_sim = _timed_run(dataclasses.replace(spec,
                                                     substrate="simulator"),
-                                mat, seed)
+                                mat)
         devs = hw.U_nodes.sharding.device_set
         print(f"  {tier}: {t_hw:.2f} s, simulator: {t_sim:.2f} s "
               f"(wall incl. compile)", flush=True)
@@ -252,9 +250,6 @@ def mesh_phase(check: Checks, *, backend: str, T_GD: int, seed: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--iters", type=int, default=40,
-                    help="outer iterations per training run")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     # imported before JAX reaches for a device: without the repo's src/
@@ -279,15 +274,14 @@ def main(argv=None) -> int:
     check = Checks()
     with jax.default_matmul_precision("highest"):
         if args.chips == 4:
-            mesh_phase(check, backend="pallas", T_GD=args.iters,
-                       seed=args.seed, n_dev=4)
+            mesh_phase(check, backend="pallas", n_dev=4)
         else:
-            kernel_phase(check, backend="pallas", seed=args.seed)
+            kernel_phase(check, backend="pallas")
             trace, mat = train_phase(check, backend="pallas",
-                                     ref_backend="xla-ref", T_GD=args.iters,
-                                     seed=args.seed, count_kernels=True)
+                                     ref_backend="xla-ref",
+                                     count_kernels=True)
             serve_phase(check, trace, mat.problem.U_star, backend="pallas",
-                        ref_backend="xla-ref", seed=args.seed)
+                        ref_backend="xla-ref")
     if check.failed:
         print(f"chip_smoke: FAILED {check.failed}", file=sys.stderr)
         return 1

@@ -11,6 +11,6 @@ from repro.api.registry import (
     SOLVERS, SolverDef, register_solver, get_solver, solver_names,
 )
 from repro.api.runner import (
-    Trace, Materialized, run_experiment, materialize, comm_time_axis,
-    system_time_axis,
+    Trace, Materialized, run_experiment, materialize, simulate,
+    comm_time_axis, system_time_axis,
 )

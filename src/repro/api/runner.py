@@ -307,19 +307,11 @@ def run_experiment(spec: ExperimentSpec, key=None, *, engine=None,
     if spec.substrate == "mesh":
         result = _run_mesh(spec, solver, mat, eng, eta, avail=avail_np)
     elif checkpoint_every is not None:
-        result = _run_segmented(spec, solver, mat, eng, eta,
-                                avail=avail_np, every=checkpoint_every,
+        result = _run_segmented(spec, mat, eng, eta, avail=avail_np,
+                                every=checkpoint_every,
                                 directory=checkpoint_dir)
     else:
-        extra = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
-        if avail_np is not None:
-            extra["avail"] = jnp.asarray(avail_np)
-        # reprolint: allow=RL002 — Materialized.adj field: SparseGraph on the sparse path, dense only below the use_sparse gate
-        result = solver.call(mat.init.U0, mat.Xg, mat.yg, mat.W, mat.adj,
-                             eta=eta, T_GD=spec.solver.T_GD,
-                             T_con=spec.solver.T_con,
-                             U_star=mat.problem.U_star, engine=eng,
-                             **extra)
+        result = simulate(spec, mat, engine=eng, avail=avail_np)
     if spec.system is not None:
         sf = getattr(result, "send_frac", None)
         time_axis = system_time_axis(
@@ -337,8 +329,32 @@ def run_experiment(spec: ExperimentSpec, key=None, *, engine=None,
                  time_axis_source=source)
 
 
-def _run_segmented(spec: ExperimentSpec, solver: SolverDef,
-                   mat: Materialized, eng, eta: float, *,
+def simulate(spec: ExperimentSpec, mat: Materialized, *, engine=None,
+             U0: jax.Array | None = None, T_GD: int | None = None,
+             avail: np.ndarray | None = None) -> RunResult:
+    """The simulator substrate's solver call, as :func:`run_experiment`
+    makes it: the engine from ``spec.engine`` (or ``engine``), η from the
+    spec and ``mat.init``, the solver's spec kwargs.  ``U0`` and ``T_GD``
+    default to the init and the spec's, for callers that run a segment;
+    ``avail`` is the availability mask of the dropout-tolerant solvers."""
+    from repro.core.engine import resolve_engine
+    solver = get_solver(spec.solver.name)
+    eng = resolve_engine(engine, spec.engine.backend,
+                         blk_d=spec.engine.blk_d)
+    extra = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
+    if avail is not None:
+        extra["avail"] = jnp.asarray(avail)
+    U0 = mat.init.U0 if U0 is None else U0
+    # reprolint: allow=RL002 — Materialized.adj field: SparseGraph on the sparse path, dense only below the use_sparse gate
+    return solver.call(U0, mat.Xg, mat.yg, mat.W, mat.adj,
+                       eta=_resolve_spec_eta(spec, mat.init),
+                       T_GD=spec.solver.T_GD if T_GD is None else T_GD,
+                       T_con=spec.solver.T_con, U_star=mat.problem.U_star,
+                       engine=eng, **extra)
+
+
+def _run_segmented(spec: ExperimentSpec, mat: Materialized, eng,
+                   eta: float, *,
                    avail: np.ndarray | None, every: int,
                    directory: str) -> RunResult:
     """The checkpoint-publishing driver: run the solver in segments of
@@ -348,20 +364,15 @@ def _run_segmented(spec: ExperimentSpec, solver: SolverDef,
     matches the unsegmented run row for row."""
     from repro.serving.publisher import publish_representation
     T_GD = spec.solver.T_GD
-    extra = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
     publish_representation(directory, 0, mat.init.U0)
     U_cur = mat.init.U0
     chunks = []
     done = 0
     while done < T_GD:
         seg = min(every, T_GD - done)
-        kw = dict(extra)
-        if avail is not None:
-            kw["avail"] = jnp.asarray(avail[done:done + seg])
-        # reprolint: allow=RL002 — Materialized.adj field: SparseGraph on the sparse path, dense only below the use_sparse gate
-        res = solver.call(U_cur, mat.Xg, mat.yg, mat.W, mat.adj, eta=eta,
-                          T_GD=seg, T_con=spec.solver.T_con,
-                          U_star=mat.problem.U_star, engine=eng, **kw)
+        res = simulate(spec, mat, engine=eng, U0=U_cur, T_GD=seg,
+                       avail=None if avail is None
+                       else avail[done:done + seg])
         done += seg
         publish_representation(directory, done, res.U_nodes)
         chunks.append(res)

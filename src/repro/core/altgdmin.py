@@ -125,7 +125,10 @@ def resolve_eta(eta, n, sigma_max=None, R_diag=None, L=None,
         return float(eta)
     if sigma_max is not None:
         return c_eta / (n * sigma_max**2)
-    sig2 = float(L * jnp.max(R_diag))
+    # η is a static float: evaluate on the concrete init even when the
+    # caller is being traced (a jitted solver call)
+    with jax.ensure_compile_time_eval():
+        sig2 = float(L * jnp.max(R_diag))
     return c_eta / (n * sig2)
 
 

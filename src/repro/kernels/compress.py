@@ -20,12 +20,9 @@ with ``ref.py`` oracles; float64 operands never reach them — the
 consensus layer's shared ``_fused_wanted`` gate routes x64 runs to the
 exact reference path, the same policy the combine kernels follow.
 
-Caveat (same family as the in-kernel Cholesky of ``altgdmin_ls``): the
-top-k selection loop uses a dynamic row gather and dynamic output
-stores.  Interpret mode (the CI path) executes it exactly; if a future
-Mosaic lowering rejects the dynamic indexing, hoist the selection to
-``ops.py`` via ``jax.lax.top_k`` (the ``ref`` oracle keeps that
-structure available).
+The top-k selection loop loads and stores rows at dynamic offsets; Mosaic
+compiles it for the TPU (``tests/test_tpu_compile.py``), so the selection
+stays in the kernel.
 """
 from __future__ import annotations
 
@@ -43,7 +40,10 @@ def _topk_kernel(m_ref, vals_ref, idx_ref, *, k: int, d: int):
 
     def select(j, s):
         top = jnp.max(s)
-        i0 = jnp.min(jnp.where(s == top, rows, d))      # first max (stable)
+        # first max (stable); a NaN norm counts as the max, as in
+        # lax.top_k, so a diverged row is selected, never row d
+        hit = (s == top) | jnp.isnan(s)
+        i0 = jnp.min(jnp.where(hit, rows, d))
         vals_ref[0, pl.ds(j, 1), :] = m_ref[0, pl.ds(i0, 1), :]
         idx_ref[0, pl.ds(j, 1), :] = jnp.full((1, 1), i0, jnp.int32)
         return jnp.where(rows == i0, -jnp.inf, s)

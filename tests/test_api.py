@@ -11,7 +11,8 @@ import pytest
 from repro.api import (CommSpec, EngineSpec, ExperimentSpec, InitSpec,
                        ProblemSpec, SolverSpec, SOLVERS, SolverDef,
                        TopologySpec, get_solver, materialize,
-                       register_solver, run_experiment, solver_names)
+                       register_solver, run_experiment, simulate,
+                       solver_names)
 from repro.core import (centralized_altgdmin, dec_altgdmin, dgd_altgdmin,
                         dif_altgdmin)
 from repro.core.engine import AltgdminEngine
@@ -189,6 +190,23 @@ def test_materialized_reuse_matches_fresh_run():
         np.testing.assert_array_equal(np.asarray(fresh.U_nodes),
                                       np.asarray(shared.U_nodes))
         assert fresh.eta == shared.eta
+
+
+def test_simulate_under_jit_matches_run_experiment():
+    """``simulate`` is the runner's simulator call; traced with the data
+    as arguments it still resolves η on the concrete init and gives the
+    run's iterate."""
+    mat = materialize(TINY, key=2)
+    run = run_experiment(TINY, key=2, materialized=mat)
+
+    def sim(Xg, yg):
+        res = simulate(TINY, dataclasses.replace(mat, Xg=Xg, yg=yg))
+        return res.U_nodes, res.eta
+
+    U, eta = jax.jit(sim)(mat.Xg, mat.yg)
+    assert float(eta) == run.eta
+    np.testing.assert_allclose(np.asarray(U), np.asarray(run.U_nodes),
+                               rtol=0, atol=1e-10)
 
 
 # --------------------------------------------------- engine & substrate

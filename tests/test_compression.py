@@ -49,6 +49,18 @@ def test_compress_topk_kernel_matches_ref():
     assert i_k.dtype == jnp.int32 and v_k.dtype == M.dtype
 
 
+def test_compress_topk_kernel_selects_nan_rows_like_ref():
+    """A diverged (NaN) row ranks first, as in lax.top_k, in index order."""
+    M = jax.random.normal(jax.random.PRNGKey(7), (2, 16, 3), jnp.float32)
+    M = M.at[0, 5, 1].set(jnp.nan).at[1, 9, 0].set(jnp.nan)
+    M = M.at[1, 2, 2].set(jnp.nan)
+    v_k, i_k = ops.compress_topk(M, 4, backend="pallas-interpret")
+    v_r, i_r = ref.ref_compress_topk(M, 4)
+    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
+    np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_r))
+    assert int(i_k[0, 0]) == 5 and list(np.asarray(i_k[1, :2])) == [2, 9]
+
+
 def test_compress_topk_full_k_covers_all_rows():
     M = jax.random.normal(jax.random.PRNGKey(4), (3, 12, 2), jnp.float32)
     vals, idx = ops.compress_topk(M, 12, backend="pallas-interpret")
