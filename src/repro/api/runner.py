@@ -44,6 +44,7 @@ from repro.core.problem import (MTRLProblem, generate_problem, node_view,
 from repro.core.spectral import SpectralInit, decentralized_spectral_init
 from repro.distributed import consensus as _consensus
 from repro.distributed.graphs import Graph, SparseGraph
+from repro.utils.spans import span
 
 
 _COMM_MODELS = {"ethernet-1gbps": _cm.ETHERNET_1GBPS,
@@ -108,34 +109,40 @@ def _as_key(key: Union[jax.Array, int, None]) -> jax.Array:
 def materialize(spec: ExperimentSpec, key=None) -> Materialized:
     """Run the setup liturgy for a spec: generate the problem, build the
     topology, run the spectral init, resolve η."""
-    key = _as_key(key)
-    p = spec.problem
-    dtype = jnp.dtype(p.dtype)
-    prob = generate_problem(jax.random.fold_in(key, 0), d=p.d, T=p.T, r=p.r,
-                            n=p.n, L=p.L, kappa=p.kappa,
-                            noise_std=p.noise_std, dtype=dtype)
-    # the init sees the full unsplit data (Algorithm 2 precedes the
-    # fold partition of Algorithm 3 line 4)
-    Xg_init, yg_init = node_view(prob)
-    if p.n_folds > 1:
-        prob = split_samples(prob, p.n_folds)
-    Xg, yg = node_view(prob)
-    graph = spec.topology.build_graph(p.L)
-    if spec.topology.use_sparse(p.L, graph):
-        sg = graph if isinstance(graph, SparseGraph) else graph.to_sparse()
-        graph = sg
-        W = spec.topology.build_sparse_weights(p.L, sg)
-        adj = sg
-    else:
-        W = jnp.asarray(spec.topology.build_weights(p.L, graph), dtype)
-        adj = jnp.asarray(graph.adj, dtype)  # reprolint: allow=RL002 — dense branch: use_sparse() declined, L below the sparse tier
-    init = decentralized_spectral_init(
-        jax.random.fold_in(key, 1), Xg_init, yg_init, W, kappa=prob.kappa,
-        mu=prob.mu, r=p.r, T_pm=spec.init.T_pm, T_con=spec.init.T_con,
-        broadcast=spec.init.broadcast)
-    eta = _resolve_spec_eta(spec, init)
-    return Materialized(problem=prob, Xg=Xg, yg=yg, graph=graph, W=W,
-                        adj=adj, init=init, eta=eta)
+    with span("materialize"):
+        key = _as_key(key)
+        p = spec.problem
+        dtype = jnp.dtype(p.dtype)
+        with span("materialize.problem"):
+            prob = generate_problem(jax.random.fold_in(key, 0), d=p.d, T=p.T,
+                                    r=p.r, n=p.n, L=p.L, kappa=p.kappa,
+                                    noise_std=p.noise_std, dtype=dtype)
+            # the init sees the full unsplit data (Algorithm 2 precedes
+            # the fold partition of Algorithm 3 line 4)
+            Xg_init, yg_init = node_view(prob)
+            if p.n_folds > 1:
+                prob = split_samples(prob, p.n_folds)
+            Xg, yg = node_view(prob)
+        with span("materialize.topology"):
+            graph = spec.topology.build_graph(p.L)
+            if spec.topology.use_sparse(p.L, graph):
+                sg = (graph if isinstance(graph, SparseGraph)
+                      else graph.to_sparse())
+                graph = sg
+                W = spec.topology.build_sparse_weights(p.L, sg)
+                adj = sg
+            else:
+                W = jnp.asarray(spec.topology.build_weights(p.L, graph), dtype)
+                adj = jnp.asarray(graph.adj, dtype)  # reprolint: allow=RL002 — dense branch: use_sparse() declined, L below the sparse tier
+        with span("materialize.spectral_init"):
+            init = decentralized_spectral_init(
+                jax.random.fold_in(key, 1), Xg_init, yg_init, W,
+                kappa=prob.kappa, mu=prob.mu, r=p.r, T_pm=spec.init.T_pm,
+                T_con=spec.init.T_con, broadcast=spec.init.broadcast)
+        with span("materialize.eta"):
+            eta = _resolve_spec_eta(spec, init)
+        return Materialized(problem=prob, Xg=Xg, yg=yg, graph=graph, W=W,
+                            adj=adj, init=init, eta=eta)
 
 
 def _resolve_spec_eta(spec: ExperimentSpec, init) -> float:
@@ -257,76 +264,79 @@ def run_experiment(spec: ExperimentSpec, key=None, *, engine=None,
     Simulator substrate only; incompatible with ``n_folds > 1`` (the
     fold schedule restarts per segment).
     """
-    from repro.core.engine import resolve_engine
-    solver = get_solver(spec.solver.name)
-    # spec-only validation runs BEFORE the expensive materialization so
-    # an invalid sweep cell fails without paying the setup liturgy: a
-    # non-default solver knob on a solver that ignores it must raise
-    # instead of silently running without it
-    for field, default in (("local_steps", 1), ("compression", None),
-                           ("compression_k", 0), ("event_threshold", 0.0),
-                           ("consensus_gamma", 1.0)):
-        value = getattr(spec.solver, field)
-        if value != default and field not in solver.spec_kwargs:
+    with span("run_experiment"):
+        from repro.core.engine import resolve_engine
+        solver = get_solver(spec.solver.name)
+        # spec-only validation runs BEFORE the expensive materialization so
+        # an invalid sweep cell fails without paying the setup liturgy: a
+        # non-default solver knob on a solver that ignores it must raise
+        # instead of silently running without it
+        for field, default in (("local_steps", 1), ("compression", None),
+                               ("compression_k", 0), ("event_threshold", 0.0),
+                               ("consensus_gamma", 1.0)):
+            value = getattr(spec.solver, field)
+            if value != default and field not in solver.spec_kwargs:
+                raise ValueError(
+                    f"solver {solver.name!r} does not consume {field} "
+                    f"(got {field}={value}); only solvers declaring it in "
+                    f"spec_kwargs honor the field")
+        # availability: the SystemSpec's fault schedule feeds the
+        # dropout-tolerant solvers; a faulty schedule on a solver with no
+        # notion of dropped nodes must raise, not silently run fault-free
+        if (spec.system is not None and not spec.system.is_always_on
+                and not solver.takes_avail):
             raise ValueError(
-                f"solver {solver.name!r} does not consume {field} "
-                f"(got {field}={value}); only solvers declaring it in "
-                f"spec_kwargs honor the field")
-    # availability: the SystemSpec's fault schedule feeds the
-    # dropout-tolerant solvers; a faulty schedule on a solver with no
-    # notion of dropped nodes must raise, not silently run fault-free
-    if (spec.system is not None and not spec.system.is_always_on
-            and not solver.takes_avail):
-        raise ValueError(
-            f"spec.system schedules node dropout but solver "
-            f"{solver.name!r} cannot consume an availability mask; use "
-            f"one of the dropout-tolerant solvers (dif_partial / "
-            f"dif_stale / dif_pushsum)")
-    avail_np = None
-    if solver.takes_avail:
-        sys_spec = spec.system if spec.system is not None else SystemSpec()
-        avail_np = sys_spec.availability_mask(spec.solver.T_GD,
-                                              spec.problem.L)
-    if checkpoint_every is not None:
-        if checkpoint_dir is None:
-            raise ValueError("checkpoint_every needs checkpoint_dir")
-        if checkpoint_every < 1:
-            raise ValueError(f"checkpoint_every must be >= 1, got "
-                             f"{checkpoint_every}")
-        if spec.substrate == "mesh":
-            raise ValueError("checkpoint publishing runs on "
-                             "substrate='simulator' only")
-        if spec.problem.n_folds > 1:
-            raise ValueError("checkpoint_every segments the run, which "
-                             "would restart the n_folds sample-split "
-                             "schedule; use n_folds <= 1")
-    mat = materialize(spec, key) if materialized is None else materialized
-    eta = _resolve_spec_eta(spec, mat.init)
-    eng = resolve_engine(engine, spec.engine.backend,
-                         blk_d=spec.engine.blk_d)
-    if spec.substrate == "mesh":
-        result = _run_mesh(spec, solver, mat, eng, eta, avail=avail_np)
-    elif checkpoint_every is not None:
-        result = _run_segmented(spec, mat, eng, eta, avail=avail_np,
-                                every=checkpoint_every,
-                                directory=checkpoint_dir)
-    else:
-        result = simulate(spec, mat, engine=eng, avail=avail_np)
-    if spec.system is not None:
-        sf = getattr(result, "send_frac", None)
-        time_axis = system_time_axis(
-            spec, solver, mat.graph, avail=avail_np,
-            send_frac=None if sf is None else np.asarray(sf))
-        source = "simulated"
-    else:
-        time_axis = comm_time_axis(spec, solver, mat.graph)
-        source = "closed_form"
-    return Trace(spec=spec, U_nodes=result.U_nodes, B_nodes=result.B_nodes,
-                 sd_max=np.asarray(result.sd_max),
-                 sd_mean=np.asarray(result.sd_mean),
-                 spread=np.asarray(result.spread), eta=result.eta,
-                 time_axis=time_axis, materialized=mat,
-                 time_axis_source=source)
+                f"spec.system schedules node dropout but solver "
+                f"{solver.name!r} cannot consume an availability mask; use "
+                f"one of the dropout-tolerant solvers (dif_partial / "
+                f"dif_stale / dif_pushsum)")
+        avail_np = None
+        if solver.takes_avail:
+            sys_spec = spec.system if spec.system is not None else SystemSpec()
+            avail_np = sys_spec.availability_mask(spec.solver.T_GD,
+                                                  spec.problem.L)
+        if checkpoint_every is not None:
+            if checkpoint_dir is None:
+                raise ValueError("checkpoint_every needs checkpoint_dir")
+            if checkpoint_every < 1:
+                raise ValueError(f"checkpoint_every must be >= 1, got "
+                                 f"{checkpoint_every}")
+            if spec.substrate == "mesh":
+                raise ValueError("checkpoint publishing runs on "
+                                 "substrate='simulator' only")
+            if spec.problem.n_folds > 1:
+                raise ValueError("checkpoint_every segments the run, which "
+                                 "would restart the n_folds sample-split "
+                                 "schedule; use n_folds <= 1")
+        mat = materialize(spec, key) if materialized is None else materialized
+        eta = _resolve_spec_eta(spec, mat.init)
+        eng = resolve_engine(engine, spec.engine.backend,
+                             blk_d=spec.engine.blk_d)
+        with span("run_experiment.solve"):
+            if spec.substrate == "mesh":
+                result = _run_mesh(spec, solver, mat, eng, eta, avail=avail_np)
+            elif checkpoint_every is not None:
+                result = _run_segmented(spec, mat, eng, eta, avail=avail_np,
+                                        every=checkpoint_every,
+                                        directory=checkpoint_dir)
+            else:
+                result = simulate(spec, mat, engine=eng, avail=avail_np)
+        with span("run_experiment.time_axis"):
+            if spec.system is not None:
+                sf = getattr(result, "send_frac", None)
+                time_axis = system_time_axis(
+                    spec, solver, mat.graph, avail=avail_np,
+                    send_frac=None if sf is None else np.asarray(sf))
+                source = "simulated"
+            else:
+                time_axis = comm_time_axis(spec, solver, mat.graph)
+                source = "closed_form"
+        return Trace(spec=spec, U_nodes=result.U_nodes, B_nodes=result.B_nodes,
+                     sd_max=np.asarray(result.sd_max),
+                     sd_mean=np.asarray(result.sd_mean),
+                     spread=np.asarray(result.spread), eta=result.eta,
+                     time_axis=time_axis, materialized=mat,
+                     time_axis_source=source)
 
 
 def simulate(spec: ExperimentSpec, mat: Materialized, *, engine=None,

@@ -56,6 +56,7 @@ from repro.core.runtime import _altgdmin_mesh, _altgdmin_virtual_mesh
 from repro.core.spectral import _qr_pos
 from repro.distributed.consensus import (ExactDiffusionCombine, get_rule,
                                          neighbor_average_matrix)
+from repro.utils.spans import span
 
 
 class ProgramCtx(NamedTuple):
@@ -314,105 +315,109 @@ def lower_simulator(program: SolverProgram) -> Callable:
 
     def run(U0, Xg, yg, topo=None, *, eta, T_GD, T_con=1, U_star=None,
             engine=None, backend=None, avail=None, **spec_kw):
-        kw = _resolve_spec(program, spec_kw)
-        rule_kw = {k: kw[k] for k in program.rule_kwargs}
-        local_steps = int(kw.get("local_steps", 1))
-        eng = resolve_engine(engine, backend)
-        same_data = Xg.ndim == 4              # no sample-split fold axis
-        if program.stacked:
-            L = U0.shape[0]
-            U_star_ = U_star if U_star is not None else U0[0]
-        else:
-            L = Xg.shape[0] if Xg.ndim == 4 else Xg.shape[1]
-            U_star_ = U_star if U_star is not None else U0
-        eta_L = eta * L
-        avail_ = _check_avail(program, avail, T_GD, L)
-        rule = get_rule(program.combine)
-
-        mix = all_sum = None
-        if program.mixer == "plain":
-            mix = eng.make_mixer(topo, T_con, rule=program.combine)
-        elif program.mixer == "neighbor":
-            mix = eng.make_neighbor_mixer(neighbor_average_matrix(topo))
-        elif program.mixer == "central":
-            def all_sum(G):
-                return jnp.sum(G, axis=0)     # fusion-center aggregation
-        elif program.mixer == "state":
-            mix = eng.make_state_mixer(topo, T_con, rule=program.combine,
-                                       **rule_kw)
-        elif program.mixer == "masked":
-            mix = eng.make_masked_mixer(topo, T_con, rule=program.combine)
-        elif program.mixer == "masked_state":
-            mix = eng.make_masked_state_mixer(topo, T_con,
-                                              rule=program.combine)
-
-        if program.aux == "iterate":
-            aux0 = U0
-        elif program.aux == "state":
-            aux0 = rule.init_state(U0, **rule_kw)
-        else:
-            aux0 = None
-
-        send_fraction = None
-        if program.records_send_frac:
-            threshold = float(kw.get("event_threshold", 0.0))
-
-            def send_fraction(Z, st):
-                return rule.send_fraction(Z, st, threshold)
-
-        def min_grad(U, fold):
-            Xb, yb = _select(Xg, yg, 2 * fold)
-            Xc, yc = _select(Xg, yg, 2 * fold + 1)
+        with span("solve.build"):
+            kw = _resolve_spec(program, spec_kw)
+            rule_kw = {k: kw[k] for k in program.rule_kwargs}
+            local_steps = int(kw.get("local_steps", 1))
+            eng = resolve_engine(engine, backend)
+            same_data = Xg.ndim == 4              # no sample-split fold axis
             if program.stacked:
-                return eng.min_grad(U, Xb, yb, Xc, yc, same_data=same_data)
-            Ub = jnp.broadcast_to(U[None], (Xb.shape[0],) + U.shape)
-            return eng.min_grad(Ub, Xb, yb, Xc, yc, same_data=same_data)
-
-        def where_live(m, a, b):
-            return jnp.where(m[:, None, None], a, b)
-
-        ctx = ProgramCtx(min_grad=min_grad, mix=mix,
-                         qr=lambda M: _qr_pos(M)[0], eta=eta, eta_L=eta_L,
-                         local_steps=local_steps, all_sum=all_sum,
-                         where_live=where_live, send_fraction=send_fraction)
-
-        if program.stacked:
-            def metrics(U_new):
-                return _metrics(U_new, U_star_)
-        else:
-            def metrics(U_new):
-                sd = subspace_distance(U_new, U_star_)
-                return (sd, sd, jnp.zeros((), U_new.dtype))
-
-        def step(carry, xt):
-            U, aux = carry
-            if program.takes_avail:
-                tau, m = xt
-                U_new, aux_new, extra = program.update(ctx, U, aux, tau, m)
+                L = U0.shape[0]
+                U_star_ = U_star if U_star is not None else U0[0]
             else:
-                U_new, aux_new, extra = program.update(ctx, U, aux, xt)
-            out = metrics(U_new)
-            if extra is not None:
-                out = out + (extra,)
-            return (U_new, aux_new), out
+                L = Xg.shape[0] if Xg.ndim == 4 else Xg.shape[1]
+                U_star_ = U_star if U_star is not None else U0
+            eta_L = eta * L
+            avail_ = _check_avail(program, avail, T_GD, L)
+            rule = get_rule(program.combine)
 
-        xs = ((jnp.arange(T_GD), avail_) if program.takes_avail
-              else jnp.arange(T_GD))
-        (U_fin, _), outs = jax.lax.scan(step, (U0, aux0), xs)
+            mix = all_sum = None
+            if program.mixer == "plain":
+                mix = eng.make_mixer(topo, T_con, rule=program.combine)
+            elif program.mixer == "neighbor":
+                mix = eng.make_neighbor_mixer(neighbor_average_matrix(topo))
+            elif program.mixer == "central":
+                def all_sum(G):
+                    return jnp.sum(G, axis=0)     # fusion-center aggregation
+            elif program.mixer == "state":
+                mix = eng.make_state_mixer(topo, T_con, rule=program.combine,
+                                           **rule_kw)
+            elif program.mixer == "masked":
+                mix = eng.make_masked_mixer(topo, T_con, rule=program.combine)
+            elif program.mixer == "masked_state":
+                mix = eng.make_masked_state_mixer(topo, T_con,
+                                                  rule=program.combine)
+
+            if program.aux == "iterate":
+                aux0 = U0
+            elif program.aux == "state":
+                aux0 = rule.init_state(U0, **rule_kw)
+            else:
+                aux0 = None
+
+            send_fraction = None
+            if program.records_send_frac:
+                threshold = float(kw.get("event_threshold", 0.0))
+
+                def send_fraction(Z, st):
+                    return rule.send_fraction(Z, st, threshold)
+
+            def min_grad(U, fold):
+                Xb, yb = _select(Xg, yg, 2 * fold)
+                Xc, yc = _select(Xg, yg, 2 * fold + 1)
+                if program.stacked:
+                    return eng.min_grad(U, Xb, yb, Xc, yc, same_data=same_data)
+                Ub = jnp.broadcast_to(U[None], (Xb.shape[0],) + U.shape)
+                return eng.min_grad(Ub, Xb, yb, Xc, yc, same_data=same_data)
+
+            def where_live(m, a, b):
+                return jnp.where(m[:, None, None], a, b)
+
+            ctx = ProgramCtx(min_grad=min_grad, mix=mix,
+                             qr=lambda M: _qr_pos(M)[0], eta=eta, eta_L=eta_L,
+                             local_steps=local_steps, all_sum=all_sum,
+                             where_live=where_live,
+                             send_fraction=send_fraction)
+
+            if program.stacked:
+                def metrics(U_new):
+                    return _metrics(U_new, U_star_)
+            else:
+                def metrics(U_new):
+                    sd = subspace_distance(U_new, U_star_)
+                    return (sd, sd, jnp.zeros((), U_new.dtype))
+
+            def step(carry, xt):
+                U, aux = carry
+                if program.takes_avail:
+                    tau, m = xt
+                    U_new, aux_new, extra = program.update(ctx, U, aux, tau, m)
+                else:
+                    U_new, aux_new, extra = program.update(ctx, U, aux, xt)
+                out = metrics(U_new)
+                if extra is not None:
+                    out = out + (extra,)
+                return (U_new, aux_new), out
+
+            xs = ((jnp.arange(T_GD), avail_) if program.takes_avail
+                  else jnp.arange(T_GD))
+        with span("solve.scan"):
+            (U_fin, _), outs = jax.lax.scan(step, (U0, aux0), xs)
         sfrac = None
         if program.records_send_frac:
             sd_max, sd_mean, spread, sfrac = outs
         else:
             sd_max, sd_mean, spread = outs
 
-        Xb, yb = _select(Xg, yg, program.refit(T_GD, local_steps))
-        if program.stacked:
-            U_out, B_fin = U_fin, eng.minimize_B(U_fin, Xb, yb)
-        else:
-            B_fin = eng.minimize_B(
-                jnp.broadcast_to(U_fin[None],
-                                 (Xb.shape[0],) + U_fin.shape), Xb, yb)
-            U_out = U_fin[None]
+        with span("solve.refit"):
+            Xb, yb = _select(Xg, yg, program.refit(T_GD, local_steps))
+            if program.stacked:
+                U_out, B_fin = U_fin, eng.minimize_B(U_fin, Xb, yb)
+            else:
+                B_fin = eng.minimize_B(
+                    jnp.broadcast_to(U_fin[None],
+                                     (Xb.shape[0],) + U_fin.shape), Xb, yb)
+                U_out = U_fin[None]
         return RunResult(U_out, B_fin, sd_max, sd_mean, spread, eta,
                          send_frac=sfrac)
 

@@ -52,6 +52,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import AltgdminEngine, resolve_engine
 from repro.core.metrics import consensus_spread, subspace_distance
+from repro.utils.spans import span
 
 
 def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
@@ -82,8 +83,9 @@ def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
     if U0.shape[0] != L:
         raise ValueError(f"need one node per device: L={U0.shape[0]} vs "
                          f"mesh axis {L}")
-    eng = resolve_engine(engine, backend)
-    update = make_update(eng)
+    with span("solve.build"):
+        eng = resolve_engine(engine, backend)
+        update = make_update(eng)
     with_metrics = U_star is not None
     has_xs = xs is not None
 
@@ -140,7 +142,9 @@ def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
                         check_vma=not eng.fused)
 
     U_dummy = U0[0] if U_star is None else U_star
-    out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
+    # one program: the scan and the final min-B
+    with span("solve.scan"):
+        out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
     if not with_metrics:
         return out
     U_fin, B_fin, sd, spread = out          # sd/spread: (L, T_GD)
@@ -178,8 +182,9 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
     if vt.n_dev != D or vt.n_nodes != L:
         raise ValueError(f"VirtualTopology is {vt.n_dev} dev × {vt.block} "
                          f"block but the run has {D} devices and L={L}")
-    eng = resolve_engine(engine, backend)
-    update = make_update(eng)
+    with span("solve.build"):
+        eng = resolve_engine(engine, backend)
+        update = make_update(eng)
     with_metrics = U_star is not None
     has_xs = xs is not None
 
@@ -224,7 +229,9 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
                         check_vma=not eng.fused)
 
     U_dummy = U0[0] if U_star is None else U_star
-    out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
+    # one program: the scan and the final min-B
+    with span("solve.scan"):
+        out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
     if not with_metrics:
         return out
     U_fin, B_fin, sd, spread = out       # sd: (D, T_GD, V), spread: (D, T)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import AltgdminEngine
+from repro.utils.spans import span
 
 
 def pack_requests(X_list, y_list, *, max_batch: int, pad_n_to: int = 8,
@@ -54,21 +55,24 @@ def pack_requests(X_list, y_list, *, max_batch: int, pad_n_to: int = 8,
     if R > max_batch:
         raise ValueError(f"got {R} requests but max_batch={max_batch}; "
                          f"the admission queue must cap batches")
-    d = np.shape(X_list[0])[-1]
-    n_pad = -(-max(np.shape(x)[0] for x in X_list) // pad_n_to) * pad_n_to
-    dt = dtype or jnp.asarray(X_list[0]).dtype
-    X = np.zeros((max_batch, n_pad, d), dt)
-    y = np.zeros((max_batch, n_pad), dt)
-    for i, (Xi, yi) in enumerate(zip(X_list, y_list)):
-        t = np.shape(Xi)[0]
-        if np.shape(yi)[0] != t:
-            raise ValueError(f"request {i}: X has {t} rows but y has "
-                             f"{np.shape(yi)[0]}")
-        X[i, :t] = np.asarray(Xi, dt)
-        y[i, :t] = np.asarray(yi, dt)
-    for i in range(R, max_batch):          # dummy slots: SPD Gram, b = 0
-        X[i] = X[0]
-    return jnp.asarray(X), jnp.asarray(y), R
+    with span("serve.pack"):
+        d = np.shape(X_list[0])[-1]
+        n_pad = (-(-max(np.shape(x)[0] for x in X_list) // pad_n_to)
+                 * pad_n_to)
+        dt = dtype or jnp.asarray(X_list[0]).dtype
+        X = np.zeros((max_batch, n_pad, d), dt)
+        y = np.zeros((max_batch, n_pad), dt)
+        for i, (Xi, yi) in enumerate(zip(X_list, y_list)):
+            t = np.shape(Xi)[0]
+            if np.shape(yi)[0] != t:
+                raise ValueError(f"request {i}: X has {t} rows but y has "
+                                 f"{np.shape(yi)[0]}")
+            X[i, :t] = np.asarray(Xi, dt)
+            y[i, :t] = np.asarray(yi, dt)
+        for i in range(R, max_batch):      # dummy slots: SPD Gram, b = 0
+            X[i] = X[0]
+    with span("serve.put"):
+        return jnp.asarray(X), jnp.asarray(y), R
 
 
 class ServingEngine:
@@ -89,8 +93,6 @@ class ServingEngine:
         self.max_batch = int(max_batch)
         self.pad_n_to = int(pad_n_to)
         self._lock = threading.Lock()
-        self.n_dispatches = 0
-        self.n_requests = 0
         self.update_representation(U, version=version)
         # one jitted closure; U rides as an argument so hot swaps hit
         # the same executable (shapes/dtype unchanged)
@@ -129,10 +131,7 @@ class ServingEngine:
         bit-consistent with the training engine's fold solve."""
         with self._lock:
             U, version = self.U, self.version
-        B = self._solve(U, X, y)
-        self.n_dispatches += 1
-        self.n_requests += X.shape[0]
-        return B, version
+        return self._solve(U, X, y), version
 
     def solve(self, X_list, y_list):
         """Ragged request list in, per-request solutions out.
@@ -140,15 +139,22 @@ class ServingEngine:
         Returns (B (R, r), theta (R, d), version): b_new per request and
         the personalized regressors θ̂ = U b_new (the basis-invariant
         quantity a drifting U is scored on)."""
-        for i, Xi in enumerate(X_list):
-            if np.shape(Xi)[0] < self.r:
-                raise ValueError(
-                    f"request {i} has T_new={np.shape(Xi)[0]} < r={self.r} "
-                    f"samples; the r-dimensional system is underdetermined")
-        X, y, R = pack_requests(X_list, y_list, max_batch=self.max_batch,
-                                pad_n_to=self.pad_n_to, dtype=self.U.dtype)
-        B_full, version = self.solve_packed(X, y)
-        B = B_full[:R]
-        theta = B @ self.U.T
-        self.n_requests -= self.max_batch - R      # count real ones only
-        return B, theta, version
+        with span("serve.solve"):
+            rows = 0
+            for i, Xi in enumerate(X_list):
+                t = np.shape(Xi)[0]
+                if t < self.r:
+                    raise ValueError(
+                        f"request {i} has T_new={t} < r={self.r} samples; "
+                        f"the r-dimensional system is underdetermined")
+                rows += t
+            X, y, R = pack_requests(X_list, y_list, max_batch=self.max_batch,
+                                    pad_n_to=self.pad_n_to, dtype=self.U.dtype)
+            slots, n_pad = X.shape[:2]
+            with span("serve.dispatch", requests=R, slots=slots, rows=rows,
+                      rows_padded=slots * n_pad):
+                B_full, version = self.solve_packed(X, y)
+            with span("serve.theta"):
+                B = B_full[:R]
+                theta = B @ self.U.T
+            return B, theta, version
