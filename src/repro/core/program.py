@@ -43,11 +43,15 @@ substrates (plus the runner's substrate dispatch) come for free.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
+import hashlib
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.altgdmin import RunResult, _metrics, _select
 from repro.core.engine import resolve_engine
@@ -305,22 +309,160 @@ def _upd_masked_state(ctx, U, cstate, tau, m):
 # lowerings
 # ----------------------------------------------------------------------
 
+# jitted scans the simulator keeps, newest last; a constant, so a sweep
+# over many graphs holds at most this many compiled loops
+SCAN_CACHE_SIZE = 16
+_SCANS: collections.OrderedDict = collections.OrderedDict()
+
+
+def clear_scan_cache() -> None:
+    """Drop every kept simulator loop.  The key reads a call's statics,
+    not the code its loop calls: after replacing a function that the
+    loop traces (an engine method, a kernel, a mixer), clear the cache
+    so that the next call traces the replacement."""
+    _SCANS.clear()
+
+
+def _content_key(x):
+    """A hashable key of a concrete topology operand's content: a dense
+    matrix by its bytes, shape and dtype, a dataclass (``SparseWeights``,
+    ``SparseGraph``) by its fields.  None where there is no content to
+    key on (a traced operand, an object that is no array)."""
+    if x is None or isinstance(x, (bool, int, float, str)):
+        return (type(x).__name__, x)
+    if isinstance(x, jax.core.Tracer):
+        return None
+    if dataclasses.is_dataclass(x):
+        parts = [type(x).__name__]
+        for f in dataclasses.fields(x):
+            k = _content_key(getattr(x, f.name))
+            if k is None:
+                return None
+            parts.append((f.name, k))
+        return tuple(parts)
+    a = np.ascontiguousarray(np.asarray(x))
+    if a.dtype == object:
+        return None
+    return (a.shape, a.dtype.str,
+            hashlib.blake2b(a.tobytes(), digest_size=16).digest())
+
+
+def _scan_key(program, eng, topo, kw, *, T_con):
+    """The static part of a simulator call: everything its jitted scan
+    builds on the host (the mixer from the topology's content, the
+    spec's knobs).  η, the data and the iteration axis are arguments,
+    whose shapes, dtypes and tree structure (``same_data``, the mask)
+    are ``jax.jit``'s own key.  None where the call has no hashable key
+    (a traced topology)."""
+    topo_key = _content_key(topo)
+    if topo_key is None:
+        return None
+    key = (program, eng.backend, eng.blk_d, T_con,
+           tuple((k, type(v), v) for k, v in sorted(kw.items())),
+           jax.config.jax_enable_x64, jax.config.jax_default_matmul_precision,
+           topo_key)
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _simulator_scan(program, eng, topo, *, T_con, rule_kw, local_steps,
+                    threshold):
+    """The iteration loop of one static configuration,
+    ``scan_fn((U0, aux0), xs, Xg, yg, U_star, eta, eta_L) -> ((U, aux),
+    outs)``.  The mixer is built here from the concrete topology; the
+    job's data and step size come in as arguments, so one jitted loop
+    serves every job of the same shapes."""
+    rule = get_rule(program.combine)
+    mix = all_sum = None
+    if program.mixer == "plain":
+        mix = eng.make_mixer(topo, T_con, rule=program.combine)
+    elif program.mixer == "neighbor":
+        mix = eng.make_neighbor_mixer(neighbor_average_matrix(topo))
+    elif program.mixer == "central":
+        def all_sum(G):
+            return jnp.sum(G, axis=0)             # fusion-center aggregation
+    elif program.mixer == "state":
+        mix = eng.make_state_mixer(topo, T_con, rule=program.combine,
+                                   **rule_kw)
+    elif program.mixer == "masked":
+        mix = eng.make_masked_mixer(topo, T_con, rule=program.combine)
+    elif program.mixer == "masked_state":
+        mix = eng.make_masked_state_mixer(topo, T_con,
+                                          rule=program.combine)
+
+    send_fraction = None
+    if program.records_send_frac:
+        def send_fraction(Z, st):
+            return rule.send_fraction(Z, st, threshold)
+
+    def where_live(m, a, b):
+        return jnp.where(m[:, None, None], a, b)
+
+    def scan_fn(carry0, xs, Xg, yg, U_star_, eta, eta_L):
+        same_data = Xg.ndim == 4              # no sample-split fold axis
+
+        def min_grad(U, fold):
+            Xb, yb = _select(Xg, yg, 2 * fold)
+            Xc, yc = _select(Xg, yg, 2 * fold + 1)
+            if program.stacked:
+                return eng.min_grad(U, Xb, yb, Xc, yc, same_data=same_data)
+            Ub = jnp.broadcast_to(U[None], (Xb.shape[0],) + U.shape)
+            return eng.min_grad(Ub, Xb, yb, Xc, yc, same_data=same_data)
+
+        ctx = ProgramCtx(min_grad=min_grad, mix=mix,
+                         qr=lambda M: _qr_pos(M)[0], eta=eta, eta_L=eta_L,
+                         local_steps=local_steps, all_sum=all_sum,
+                         where_live=where_live, send_fraction=send_fraction)
+
+        if program.stacked:
+            def metrics(U_new):
+                return _metrics(U_new, U_star_)
+        else:
+            def metrics(U_new):
+                sd = subspace_distance(U_new, U_star_)
+                return (sd, sd, jnp.zeros((), U_new.dtype))
+
+        def step(carry, xt):
+            U, aux = carry
+            if program.takes_avail:
+                tau, m = xt
+                U_new, aux_new, extra = program.update(ctx, U, aux, tau, m)
+            else:
+                U_new, aux_new, extra = program.update(ctx, U, aux, xt)
+            out = metrics(U_new)
+            if extra is not None:
+                out = out + (extra,)
+            return (U_new, aux_new), out
+
+        return jax.lax.scan(step, carry0, xs)
+
+    return scan_fn
+
+
 def lower_simulator(program: SolverProgram) -> Callable:
     """Stacked single-host simulator: ``run(U0, Xg, yg, topo, *, eta,
     T_GD, T_con, ...) -> RunResult``, trajectory-bit-identical to the
     legacy :mod:`repro.core.altgdmin` driver on both engine backends.
     ``topo`` is the mixing matrix (``"W"`` programs), the adjacency
     (``"adj"``), or absent (``"none"``) — the registry's per-topology
-    call convention, preserved."""
+    call convention, preserved.
+
+    The iteration loop is jitted once per static key (:func:`_scan_key`)
+    and kept in a small LRU shared by every program, so jobs that
+    differ only in their data and step size trace and lower it once;
+    the ``solve.scan`` span counts ``cached=1`` when the loop came from
+    the cache.  The final B refit stays outside the jitted loop, as the
+    legacy drivers run it."""
 
     def run(U0, Xg, yg, topo=None, *, eta, T_GD, T_con=1, U_star=None,
             engine=None, backend=None, avail=None, **spec_kw):
         with span("solve.build"):
             kw = _resolve_spec(program, spec_kw)
-            rule_kw = {k: kw[k] for k in program.rule_kwargs}
             local_steps = int(kw.get("local_steps", 1))
             eng = resolve_engine(engine, backend)
-            same_data = Xg.ndim == 4              # no sample-split fold axis
             if program.stacked:
                 L = U0.shape[0]
                 U_star_ = U_star if U_star is not None else U0[0]
@@ -329,80 +471,39 @@ def lower_simulator(program: SolverProgram) -> Callable:
                 U_star_ = U_star if U_star is not None else U0
             eta_L = eta * L
             avail_ = _check_avail(program, avail, T_GD, L)
-            rule = get_rule(program.combine)
-
-            mix = all_sum = None
-            if program.mixer == "plain":
-                mix = eng.make_mixer(topo, T_con, rule=program.combine)
-            elif program.mixer == "neighbor":
-                mix = eng.make_neighbor_mixer(neighbor_average_matrix(topo))
-            elif program.mixer == "central":
-                def all_sum(G):
-                    return jnp.sum(G, axis=0)     # fusion-center aggregation
-            elif program.mixer == "state":
-                mix = eng.make_state_mixer(topo, T_con, rule=program.combine,
-                                           **rule_kw)
-            elif program.mixer == "masked":
-                mix = eng.make_masked_mixer(topo, T_con, rule=program.combine)
-            elif program.mixer == "masked_state":
-                mix = eng.make_masked_state_mixer(topo, T_con,
-                                                  rule=program.combine)
-
+            rule_kw = {k: kw[k] for k in program.rule_kwargs}
             if program.aux == "iterate":
                 aux0 = U0
             elif program.aux == "state":
-                aux0 = rule.init_state(U0, **rule_kw)
+                aux0 = get_rule(program.combine).init_state(U0, **rule_kw)
             else:
                 aux0 = None
-
-            send_fraction = None
-            if program.records_send_frac:
-                threshold = float(kw.get("event_threshold", 0.0))
-
-                def send_fraction(Z, st):
-                    return rule.send_fraction(Z, st, threshold)
-
-            def min_grad(U, fold):
-                Xb, yb = _select(Xg, yg, 2 * fold)
-                Xc, yc = _select(Xg, yg, 2 * fold + 1)
-                if program.stacked:
-                    return eng.min_grad(U, Xb, yb, Xc, yc, same_data=same_data)
-                Ub = jnp.broadcast_to(U[None], (Xb.shape[0],) + U.shape)
-                return eng.min_grad(Ub, Xb, yb, Xc, yc, same_data=same_data)
-
-            def where_live(m, a, b):
-                return jnp.where(m[:, None, None], a, b)
-
-            ctx = ProgramCtx(min_grad=min_grad, mix=mix,
-                             qr=lambda M: _qr_pos(M)[0], eta=eta, eta_L=eta_L,
-                             local_steps=local_steps, all_sum=all_sum,
-                             where_live=where_live,
-                             send_fraction=send_fraction)
-
-            if program.stacked:
-                def metrics(U_new):
-                    return _metrics(U_new, U_star_)
-            else:
-                def metrics(U_new):
-                    sd = subspace_distance(U_new, U_star_)
-                    return (sd, sd, jnp.zeros((), U_new.dtype))
-
-            def step(carry, xt):
-                U, aux = carry
-                if program.takes_avail:
-                    tau, m = xt
-                    U_new, aux_new, extra = program.update(ctx, U, aux, tau, m)
-                else:
-                    U_new, aux_new, extra = program.update(ctx, U, aux, xt)
-                out = metrics(U_new)
-                if extra is not None:
-                    out = out + (extra,)
-                return (U_new, aux_new), out
-
             xs = ((jnp.arange(T_GD), avail_) if program.takes_avail
                   else jnp.arange(T_GD))
-        with span("solve.scan"):
-            (U_fin, _), outs = jax.lax.scan(step, (U0, aux0), xs)
+
+            key = _scan_key(program, eng, topo, kw, T_con=T_con)
+            scan_fn = _SCANS.pop(key, None) if key is not None else None
+            cached = scan_fn is not None
+            if not cached:
+                build = functools.partial(
+                    _simulator_scan, program, eng, topo, T_con=T_con,
+                    rule_kw=rule_kw, local_steps=local_steps,
+                    threshold=float(kw.get("event_threshold", 0.0)))
+                if key is None:
+                    scan_fn = build()
+                else:
+                    # the mixer's set-up runs on the concrete topology
+                    # even under an outer trace: a kept loop holds no
+                    # tracer
+                    with jax.ensure_compile_time_eval():
+                        scan_fn = jax.jit(build())
+            if key is not None:
+                _SCANS[key] = scan_fn                 # the newest last
+                while len(_SCANS) > SCAN_CACHE_SIZE:
+                    _SCANS.popitem(last=False)
+        with span("solve.scan", cached=int(cached)):
+            (U_fin, _), outs = scan_fn((U0, aux0), xs, Xg, yg, U_star_, eta,
+                                       eta_L)
         sfrac = None
         if program.records_send_frac:
             sd_max, sd_mean, spread, sfrac = outs

@@ -13,10 +13,14 @@ contract:
     lowering (L = devices × block) agree with the simulator ≤ 1e-8 for
     all 12 solvers — run in a subprocess with 8 fake host devices,
     like tests/test_runtime_mesh.py;
+  * the simulator lowering jits its loop once per static key and keeps
+    it: a job on fresh data of the same shapes reuses it without tracing
+    or lowering, and any changed static builds a new one;
   * the registry metadata round-trips the program (topology / combine /
     spec_kwargs / takes_avail), and repro.core.runtime holds only the
     two substrate skeletons (tools/check_runtime_clean.py's invariant).
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -33,8 +37,10 @@ from repro.api.registry import get_solver, solver_names
 from repro.core import altgdmin as alg
 from repro.core import (decentralized_spectral_init, generate_problem,
                         node_view)
+from repro.core import program as program_mod
 from repro.core.program import get_program, program_names
 from repro.distributed import graphs, mixing
+from repro.utils.spans import STATS, span
 
 ALL_SOLVERS = ("dif_altgdmin", "dec_altgdmin", "centralized_altgdmin",
                "dgd_altgdmin", "exact_diffusion", "beyond_central",
@@ -163,6 +169,216 @@ def test_simulator_lowering_bitwise_vs_legacy(name, backend, prob8):
     else:
         np.testing.assert_array_equal(np.asarray(ref.send_frac),
                                       np.asarray(new.send_frac))
+
+
+# --------------------------------------- the simulator's kept loops
+# lower_simulator jits its scan once per static key and keeps it; a job
+# on fresh data of the same shapes and statics reuses it.
+
+# one program per mixer family: plain, neighbor, central, state,
+# masked, masked_state
+CACHE_SOLVERS = ("dif_altgdmin", "dgd_altgdmin", "centralized_altgdmin",
+                 "dif_topk", "dif_partial", "dif_stale")
+
+
+@pytest.fixture(scope="module")
+def prob8_fresh(prob8):
+    """prob8's shapes, graph and step size on fresh data: its own
+    problem, init and availability mask, and W and the adjacency as new
+    buffers of the same values (the key reads content, not identity)."""
+    L = prob8["W"].shape[0]
+    prob = generate_problem(jax.random.PRNGKey(5), d=16, T=24, r=2, n=20,
+                            L=L, kappa=1.2)
+    Xg, yg = node_view(prob)
+    init = decentralized_spectral_init(
+        jax.random.PRNGKey(6), Xg, yg, prob8["W"], kappa=prob.kappa,
+        mu=prob.mu, r=2, T_pm=8, T_con=4)
+    avail = jnp.asarray(np.random.default_rng(1).random((3, L)) > 0.3)
+    return dict(prob8, prob=prob, Xg=Xg, yg=yg, U0=init.U0, avail=avail,
+                W=jnp.array(prob8["W"]), adj=jnp.array(prob8["adj"]))
+
+
+def _solve_scan_span(monkeypatch, fn):
+    """``fn()`` with the program's spans recorded: its result, and the
+    ``cached`` count and compile-path stats of the one ``solve.scan``
+    span it opened."""
+    opened = []
+
+    class Spy(span):
+        __slots__ = ()
+
+        def __init__(self, name, **counts):
+            super().__init__(name, **counts)
+            opened.append((name, counts, self))
+
+    monkeypatch.setattr(program_mod, "span", Spy)
+    out = fn()
+    jax.block_until_ready(out.U_nodes)
+    (counts, s), = [(c, s) for n, c, s in opened if n == "solve.scan"]
+    return out, counts["cached"], s.compile_path or dict.fromkeys(STATS, 0)
+
+
+def _assert_bitwise(ref, new, tag):
+    for field in ("U_nodes", "B_nodes", "sd_max", "sd_mean", "spread",
+                  "send_frac"):
+        a, b = getattr(ref, field), getattr(new, field)
+        assert (a is None) == (b is None), f"{tag}: {field}"
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{tag}: {field}")
+
+
+@pytest.mark.parametrize("backend", ["xla-ref", "pallas-interpret"])
+@pytest.mark.parametrize("name", CACHE_SOLVERS)
+def test_simulator_scan_is_reused_on_fresh_data(name, backend, prob8,
+                                                prob8_fresh, monkeypatch):
+    """A second solve on a fresh problem of the same shapes takes the
+    kept loop (``cached=1``, nothing traced or lowered in
+    ``solve.scan``) and still equals the legacy driver bit for bit."""
+    _lowered(name, prob8, backend)
+    new, cached, stats = _solve_scan_span(
+        monkeypatch, lambda: _lowered(name, prob8_fresh, backend))
+    assert cached == 1
+    assert (stats["traces"], stats["lowerings"]) == (0, 0), stats
+    _assert_bitwise(_legacy(name, prob8_fresh, backend), new,
+                    f"{name}/{backend}")
+
+
+def _topk(pb, W, **kw):
+    """dif_topk through the program's lowering and the legacy driver:
+    a state mixer with a rule knob, whose set-up reads W on the host."""
+    common = dict(U_star=pb["prob"].U_star, backend="xla-ref", **kw)
+    return (lambda: get_solver("dif_topk").fn(pb["U0"], pb["Xg"], pb["yg"],
+                                              W, **common),
+            lambda: alg.dif_topk_altgdmin(pb["U0"], pb["Xg"], pb["yg"], W,
+                                          **common))
+
+
+def _changed(pb, change):
+    """dif_topk's call with one static or argument changed from the
+    kept call's, and its W."""
+    kw = dict(eta=pb["eta"], T_GD=pb["T_GD"], T_con=2, compression_k=3)
+    W = pb["W"]
+    if change == "W":                   # same graph, other weights
+        W = 0.5 * (W + jnp.eye(W.shape[0], dtype=W.dtype))
+    elif change is not None:
+        kw[change] = {"eta": 1.1 * kw["eta"], "T_GD": 2, "T_con": 3,
+                      "compression_k": 2}[change]
+    return _topk(pb, W, **kw)
+
+
+@pytest.mark.parametrize("change", ["T_con", "compression_k", "W"])
+def test_simulator_scan_cache_key_sees_every_static(change, prob8,
+                                                    monkeypatch):
+    """Changing T_con, a rule knob or W's values builds a new loop
+    (``cached=0``) whose answer is the legacy driver's: a kept loop is
+    never reused for a call it does not fit."""
+    _changed(prob8, None)[0]()          # the unchanged call's loop is kept
+    lowered, legacy = _changed(prob8, change)
+    new, cached, _ = _solve_scan_span(monkeypatch, lowered)
+    assert cached == 0
+    _assert_bitwise(legacy(), new, change)
+
+
+@pytest.mark.parametrize("change", ["eta", "T_GD"])
+def test_simulator_scan_takes_eta_and_T_GD_as_arguments(change, prob8,
+                                                        monkeypatch):
+    """η and the iteration count are arguments of the kept loop: a new
+    step size (as each job's spectral init estimates its own) reuses it
+    with nothing traced or lowered, a new T_GD reuses it and lowers the
+    new length; both give the legacy answer bit for bit."""
+    _changed(prob8, None)[0]()
+    lowered, legacy = _changed(prob8, change)
+    new, cached, stats = _solve_scan_span(monkeypatch, lowered)
+    assert cached == 1
+    assert (stats["lowerings"] == 0) == (change == "eta"), stats
+    _assert_bitwise(legacy(), new, change)
+
+
+@pytest.mark.parametrize("name", ["dif_altgdmin", "dgd_altgdmin"])
+def test_simulator_scan_built_under_a_trace_holds_no_tracer(name, prob8,
+                                                            monkeypatch):
+    """A loop first built while an outer trace runs (``make_jaxpr``, as
+    the dispatch-budget linter traces) is reused by a plain call after
+    it, with the legacy answer.  In float32 the fused backends hoist the
+    combine onto W^{T_con} (or the neighbour average), computed when the
+    mixer is built: that set-up must not be staged into the outer
+    trace."""
+    f32 = {k: prob8[k].astype(jnp.float32)
+           for k in ("Xg", "yg", "U0", "W", "adj")}
+    prob = dataclasses.replace(
+        prob8["prob"], U_star=prob8["prob"].U_star.astype(jnp.float32))
+    pb = dict(prob8, prob=prob, **f32)
+    jax.make_jaxpr(lambda Xg: _lowered(name, dict(pb, Xg=Xg),
+                                       "pallas-interpret").U_nodes)(pb["Xg"])
+    new, cached, _ = _solve_scan_span(
+        monkeypatch, lambda: _lowered(name, pb, "pallas-interpret"))
+    assert cached == 1
+    _assert_bitwise(_legacy(name, pb, "pallas-interpret"), new, name)
+
+
+def test_clear_scan_cache_lets_a_replaced_engine_method_be_traced(
+        prob8, monkeypatch):
+    """The key reads statics, not code: a loop kept before an engine
+    method is replaced (as a planted fault replaces one) is still
+    taken, and after :func:`clear_scan_cache` the next call traces the
+    replacement and gives its legacy answer."""
+    from repro.core.engine import AltgdminEngine
+    kw = dict(eta=prob8["eta"], T_GD=prob8["T_GD"], T_con=2,
+              U_star=prob8["prob"].U_star, backend="xla-ref")
+    args = (prob8["U0"], prob8["Xg"], prob8["yg"], prob8["W"])
+    s = get_solver("dif_altgdmin")
+    s.fn(*args, **kw)                   # the unpatched loop is kept
+    real = AltgdminEngine.min_grad
+
+    def doubled(self, *a, **k):
+        B, G = real(self, *a, **k)
+        return B, 2.0 * G
+    monkeypatch.setattr(AltgdminEngine, "min_grad", doubled)
+    _, cached, _ = _solve_scan_span(monkeypatch, lambda: s.fn(*args, **kw))
+    assert cached == 1
+    program_mod.clear_scan_cache()
+    new, cached, _ = _solve_scan_span(monkeypatch, lambda: s.fn(*args, **kw))
+    assert cached == 0
+    _assert_bitwise(alg.dif_altgdmin(*args, **kw), new, "patched")
+
+
+def test_simulator_scan_with_a_traced_topology_is_not_kept(prob8,
+                                                          monkeypatch):
+    """A topology that is itself traced has no content to key on: each
+    call builds its loop (``cached=0``), as the legacy driver does, and
+    gives the legacy answer."""
+    s = get_solver("dif_altgdmin")
+    kw = dict(eta=prob8["eta"], T_GD=prob8["T_GD"], T_con=2,
+              U_star=prob8["prob"].U_star, backend="xla-ref")
+    ref = alg.dif_altgdmin(prob8["U0"], prob8["Xg"], prob8["yg"],
+                           prob8["W"], **kw)
+    for _ in range(2):
+        new, cached, _ = _solve_scan_span(monkeypatch, lambda: jax.jit(
+            lambda W: s.fn(prob8["U0"], prob8["Xg"], prob8["yg"], W, **kw)
+        )(prob8["W"]))
+        assert cached == 0
+        np.testing.assert_array_equal(np.asarray(ref.U_nodes),
+                                      np.asarray(new.U_nodes))
+
+
+def test_scan_cache_keeps_the_newest_entries(prob8, monkeypatch):
+    """The kept loops are an LRU of ``SCAN_CACHE_SIZE`` entries: with
+    room for two, a third key drops the one used least recently."""
+    assert program_mod.SCAN_CACHE_SIZE == 16
+    monkeypatch.setattr(program_mod, "SCAN_CACHE_SIZE", 2)
+    s = get_solver("dif_altgdmin")
+    kw = dict(eta=prob8["eta"], T_GD=2, U_star=prob8["prob"].U_star,
+              backend="xla-ref")
+
+    def solve(T_con):
+        return _solve_scan_span(monkeypatch, lambda: s.fn(
+            prob8["U0"], prob8["Xg"], prob8["yg"], prob8["W"], T_con=T_con,
+            **kw))[1]
+    assert [solve(1), solve(2), solve(1)] == [0, 0, 1]   # 1 is now newest
+    assert solve(3) == 0                                  # 2 is dropped
+    assert len(program_mod._SCANS) == 2
+    assert [solve(1), solve(3), solve(2)] == [1, 1, 0]
 
 
 # --------------------------------------- mesh / virtual-mesh parity

@@ -105,6 +105,25 @@ def test_job_and_batch_spans_nest_in_a_profiler_trace(tmp_path):
     assert stats["rows_padded"] == X.shape[0] * X.shape[1] == 4 * 16
 
 
+def test_a_second_job_lowers_only_its_spectral_init(tmp_path):
+    """Jobs that differ only in their data, each with the step size its
+    own spectral init estimates, share the solver's jitted loop: the
+    second job's ``repro.solve.scan`` counts ``cached=1`` and traces and
+    lowers nothing, and the job's lowerings are the spectral init's
+    three scans."""
+    spec = ExperimentSpec.from_dict(SPEC)
+    assert spec.solver.eta is None
+    jax.block_until_ready(run_experiment(spec, jax.random.PRNGKey(0)).U_nodes)
+    ev = traced_events(tmp_path, lambda: jax.block_until_ready(
+        run_experiment(spec, jax.random.PRNGKey(1)).U_nodes))
+    (_, _, scan), = ev["repro.solve.scan"]
+    assert (scan["cached"], scan["traces"], scan["lowerings"]) == (1, 0, 0)
+    (_, _, init), = ev["repro.materialize.spectral_init"]
+    assert init["lowerings"] == 3
+    assert sum(st["lowerings"] for times in ev.values()
+               for _, _, st in times) == 3
+
+
 def test_a_fresh_closure_lowers_and_a_cached_function_does_not():
     f = jax.jit(lambda x: x * 3.0 + 1.0)
     with span("probe") as first:
