@@ -18,8 +18,9 @@ p=0.5, Metropolis weights), in float32 with x64 off and
 
 Four chips (``--chips 4``) runs only Dif-AltGDmin on the mesh substrate
 (L=4, one node per chip) and on the virtual-node tier (L=20, five per
-chip), each against the simulator run of the same spec, and checks that
-the result is sharded over four distinct devices.
+chip), each against the simulator run of the same spec (U and the final
+per-node B), and checks that the result is sharded over four distinct
+devices.
 
 The last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -44,6 +45,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 # (~sqrt(600)·eps ≈ 3e-6 relative per Gram/gradient sum); Dif-AltGDmin
 # contracts, so the gap stays near that level instead of compounding.
 TOL_U = 1e-4          # max |U_a − U_b| over entries of orthonormal bases
+TOL_B = 1e-4          # max |B_a − B_b| / max |B_b| over the final refits
 TOL_SERVE = 1e-4      # max |θ_a − θ_b| / max |θ_b| over served requests
 TOL_KERNEL = 1e-5     # max |kernel − oracle| / max |oracle|
 T_GD = 40             # outer iterations per training run
@@ -226,6 +228,7 @@ def serve_phase(check: Checks, trace, U_star, *, backend: str,
 def mesh_phase(check: Checks, *, backend: str, n_dev: int) -> None:
     """Mesh substrate (one node per device) and virtual tier (five
     nodes per device), each against the simulator run of the spec."""
+    import numpy as np
     from repro.api import materialize
     for L, tier in ((n_dev, "mesh"), (5 * n_dev, "virtual")):
         spec = experiment1_spec(backend=backend, L=L, substrate="mesh")
@@ -245,6 +248,12 @@ def mesh_phase(check: Checks, *, backend: str, n_dev: int) -> None:
         check(f"{tier} vs simulator", du <= TOL_U,
               f"max |ΔU| = {du:.3e} (tol {TOL_U:g}); final sd_max "
               f"{float(hw.sd_max[-1]):.6e} vs {float(sim.sd_max[-1]):.6e}")
+        # per-node refits: a node's B placed on another node's chip
+        # leaves U (the nodes agree on it) right and B wrong
+        db = (_max_abs(hw.B_nodes, sim.B_nodes)
+              / float(np.max(np.abs(np.asarray(sim.B_nodes)))))
+        check(f"{tier} B vs simulator", db <= TOL_B,
+              f"max |ΔB| / max |B| = {db:.3e} (tol {TOL_B:g})")
 
 
 def main(argv=None) -> int:

@@ -12,11 +12,12 @@ Substrates:
     (:mod:`repro.core.altgdmin`), any topology/solver;
   * ``"mesh"``      — the shard_map runtime (one node per device,
     AGREE = collective-permute gossip).  Requires a mesh-capable solver
-    and L = available devices; ANY weight scheme runs — circulant
-    weights lower to the native uniform ring form, and every other
-    scheme (metropolis/equal_neighbor/lazy on arbitrary graphs) is
-    decomposed into per-shift, per-device weights by the consensus
-    layer.  The min-B and gradient phases route through the same
+    and L = the mesh's devices (``spec.n_devices``, else all of them),
+    or a multiple of them on the virtual-node tier; ANY weight scheme
+    runs — circulant weights lower to the native uniform ring form, and
+    every other scheme (metropolis/equal_neighbor/lazy on arbitrary
+    graphs) is decomposed into per-shift, per-device weights by the
+    consensus layer.  The min-B and gradient phases route through the same
     :class:`AltgdminEngine` backend as the simulator, so
     ``pallas``/``pallas-interpret`` reach hardware nodes.
 
@@ -407,18 +408,19 @@ def _run_mesh(spec: ExperimentSpec, solver: SolverDef, mat: Materialized,
     if p.n_folds > 1:
         raise ValueError("substrate='mesh' does not support sample "
                          "splitting (n_folds > 1)")
-    n_dev = jax.device_count()
+    devices = _mesh_devices(spec)
+    n_dev = len(devices)
     if p.L != n_dev:
-        if (solver.virtual_mesh_fn is not None and n_dev >= 1
-                and p.L % n_dev == 0):
-            return _run_virtual_mesh(spec, solver, mat, eng, eta, n_dev,
+        if solver.virtual_mesh_fn is not None and p.L % n_dev == 0:
+            return _run_virtual_mesh(spec, solver, mat, eng, eta, devices,
                                      avail=avail)
         raise ValueError(f"substrate='mesh' needs one device per node: "
-                         f"L={p.L} but {n_dev} devices are available "
+                         f"L={p.L} but the mesh has {n_dev} devices "
                          f"(the virtual-node tier needs a solver with a "
                          f"virtual mesh runtime and n_dev | L)")
     mesh = jax.make_mesh((p.L,), ("nodes",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices)
     kw = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
     if avail is not None:
         kw.update(avail=jnp.asarray(avail))
@@ -440,8 +442,21 @@ def _run_mesh(spec: ExperimentSpec, solver: SolverDef, mat: Materialized,
         engine=eng, U_star=mat.problem.U_star, **kw)
 
 
+def _mesh_devices(spec: ExperimentSpec) -> list:
+    """The mesh substrate's devices: the first ``spec.n_devices`` of
+    ``jax.devices()``, or all of them where the spec leaves it None."""
+    devices = jax.devices()
+    if spec.n_devices is None:
+        return devices
+    if len(devices) < spec.n_devices:
+        raise ValueError(f"the spec deploys over n_devices="
+                         f"{spec.n_devices} but JAX sees {len(devices)} "
+                         f"devices")
+    return devices[:spec.n_devices]
+
+
 def _run_virtual_mesh(spec: ExperimentSpec, solver: SolverDef,
-                      mat: Materialized, eng, eta: float, n_dev: int,
+                      mat: Materialized, eng, eta: float, devices: list,
                       avail: np.ndarray | None = None) -> RunResult:
     """The virtual-node mesh tier: L = n_dev × block, contiguous blocks
     of virtual nodes per device — co-located gossip is an on-device
@@ -458,9 +473,14 @@ def _run_virtual_mesh(spec: ExperimentSpec, solver: SolverDef,
         W = mat.W
     if not isinstance(W, SparseWeights):
         W = SparseWeights.from_dense(np.asarray(W))
-    vt = _consensus.VirtualTopology.from_weights(W, n_dev)
-    mesh = jax.make_mesh((n_dev,), ("nodes",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    with span("virtual.topology") as sp:
+        vt = _consensus.VirtualTopology.from_weights(W, len(devices))
+        sp.count(devices=vt.n_dev, block=vt.block,
+                 shift_classes=len(vt.dev_shifts),
+                 cross_edges=vt.n_cross_entries)
+    mesh = jax.make_mesh((vt.n_dev,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices)
     kw = {k: getattr(spec.solver, k) for k in solver.spec_kwargs}
     if avail is not None:
         kw.update(avail=jnp.asarray(avail))

@@ -19,7 +19,8 @@ The five sub-specs mirror the liturgy's stages:
   * :class:`EngineSpec`   — kernel backend for the iteration engine;
 
 plus ``substrate`` selecting the single-host simulator or the shard_map
-mesh runtime, and :class:`CommSpec` for the emulated wall-clock axis.
+mesh runtime (``n_devices`` the deployment's chip count there), and
+:class:`CommSpec` for the emulated wall-clock axis.
 """
 from __future__ import annotations
 
@@ -390,12 +391,17 @@ class ExperimentSpec:
     comm: CommSpec = CommSpec()
     system: Optional[SystemSpec] = None
     substrate: str = "simulator"
+    # substrate="mesh": the first n_devices of jax.devices() (None: all)
+    n_devices: Optional[int] = None
     name: str = ""
 
     def __post_init__(self):
         if self.substrate not in SUBSTRATES:
             raise ValueError(f"unknown substrate {self.substrate!r}; "
                              f"expected one of {SUBSTRATES}")
+        if self.n_devices is not None and self.n_devices < 1:
+            raise ValueError(f"n_devices must be >= 1 or None, got "
+                             f"{self.n_devices}")
 
     # ------------------------------------------------- JSON round-trip
 

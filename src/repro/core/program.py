@@ -620,6 +620,29 @@ def lower_mesh(program: SolverProgram) -> Callable:
     return run
 
 
+def _virtual_wire(program: SolverProgram, rule, vt, U0, T_con: int,
+                  rule_kw: dict) -> dict:
+    """What one device sends per outer iteration on the virtual tier, as
+    the rule's ``CommSignature`` prices it (JX004 holds the lowering to
+    the count): ``ppermutes_per_iter``, rounds × cross-device shift
+    classes × the program's ``wire_virtual``, and
+    ``wire_bytes_per_iter``, the block's payload over them (the
+    fusion center's all-reduce of one block sum instead)."""
+    d, r = U0.shape[1], U0.shape[2]
+    itemsize = jnp.dtype(U0.dtype).itemsize
+    sig = rule.signature(T_con, d=d, r=r, **rule_kw)
+    if program.mixer == "central":
+        return dict(ppermutes_per_iter=0,
+                    wire_bytes_per_iter=sig.bytes_per_iter(
+                        d * r, itemsize, vt.n_dev, 0))
+    S = vt.block_sends_per_round
+    budget = program.dispatch_budget
+    wire = 1 if budget is None else budget.wire_virtual
+    return dict(ppermutes_per_iter=sig.rounds_per_iter * S * wire,
+                wire_bytes_per_iter=vt.block * sig.bytes_per_iter(
+                    d * r, itemsize, vt.n_nodes, S))
+
+
 def lower_virtual_mesh(program: SolverProgram) -> Callable:
     """Virtual-node block-tier lowering (L = devices × block) on
     :func:`~repro.core.runtime._altgdmin_virtual_mesh`: each device is a
@@ -699,7 +722,9 @@ def lower_virtual_mesh(program: SolverProgram) -> Callable:
                                       make_update=make_update,
                                       engine=engine, backend=backend,
                                       U_star=U_star, init_aux=init_aux,
-                                      xs=xs)
+                                      xs=xs,
+                                      wire=_virtual_wire(program, rule, vt,
+                                                         U0, T_con, rule_kw))
 
     run.__name__ = run.__qualname__ = f"{program.name}__virtual_mesh"
     run.__doc__ = (f"Virtual-mesh lowering of the {program.name!r} solver "

@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import AltgdminEngine, resolve_engine
@@ -132,14 +133,16 @@ def _altgdmin_mesh(U0, Xg, yg, mesh, axis_name: str, *, eta: float,
 
     sharded = P(axis_name)
     out_specs = ((sharded,) * 4) if with_metrics else (sharded, sharded)
-    # a pallas_call's outputs carry no varying-axes type, so the check
-    # is off exactly when the engine dispatches Pallas kernels
-    run = jax.shard_map(body, mesh=mesh,
-                        in_specs=(sharded, sharded, sharded, P())
-                        + ((P(),) if has_xs else ()),
-                        out_specs=out_specs,
-                        axis_names={axis_name},
-                        check_vma=not eng.fused)
+    # one jitted program; called eagerly, a shard_map runs its body
+    # primitive by primitive, each a program of its own.  A pallas_call's
+    # outputs carry no varying-axes type, so the check is off exactly
+    # when the engine dispatches Pallas kernels
+    run = jax.jit(jax.shard_map(body, mesh=mesh,
+                                in_specs=(sharded, sharded, sharded, P())
+                                + ((P(),) if has_xs else ()),
+                                out_specs=out_specs,
+                                axis_names={axis_name},
+                                check_vma=not eng.fused))
 
     U_dummy = U0[0] if U_star is None else U_star
     # one program: the scan and the final min-B
@@ -158,7 +161,7 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
                            eta: float, T_GD: int, make_update,
                            engine: AltgdminEngine | None,
                            backend: str | None, U_star, init_aux=None,
-                           xs=None):
+                           xs=None, wire: dict | None = None):
     """Shared shard_map skeleton for the VIRTUAL-NODE mesh tier:
     L = devices × block nodes, each device holding a contiguous
     (block, d, r) slab of iterates and the matching data shard.  The
@@ -174,7 +177,13 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
     :func:`_altgdmin_mesh`, except the per-device iterate is the
     (block, d, r) slab and ``min_grad`` is node-batched over it.
     Federated structure is preserved: only the (block, d, r) iterate
-    slab (or the rule's compact payload) crosses the wire, never data."""
+    slab (or the rule's compact payload) crosses the wire, never data.
+
+    The node-axis operands are placed on the mesh first (span
+    ``repro.virtual.shard``), so that resharding is a phase of its own;
+    ``repro.solve.scan`` counts ``devices``, ``gathers_per_iter`` (the
+    metrics' all-gather) and what ``wire`` holds, the lowering's
+    ``ppermutes_per_iter`` and ``wire_bytes_per_iter``."""
     from repro.core.altgdmin import RunResult
 
     D = mesh.shape[axis_name]
@@ -219,18 +228,24 @@ def _altgdmin_virtual_mesh(U0, Xg, yg, mesh, axis_name: str, *, vt,
 
     sharded = P(axis_name)
     out_specs = ((sharded,) * 4) if with_metrics else (sharded, sharded)
-    # a pallas_call's outputs carry no varying-axes type, so the check
-    # is off exactly when the engine dispatches Pallas kernels
-    run = jax.shard_map(body, mesh=mesh,
-                        in_specs=(sharded, sharded, sharded, P())
-                        + ((P(),) if has_xs else ()),
-                        out_specs=out_specs,
-                        axis_names={axis_name},
-                        check_vma=not eng.fused)
+    # one jitted program; called eagerly, a shard_map runs its body
+    # primitive by primitive, each a program of its own.  A pallas_call's
+    # outputs carry no varying-axes type, so the check is off exactly
+    # when the engine dispatches Pallas kernels
+    run = jax.jit(jax.shard_map(body, mesh=mesh,
+                                in_specs=(sharded, sharded, sharded, P())
+                                + ((P(),) if has_xs else ()),
+                                out_specs=out_specs,
+                                axis_names={axis_name},
+                                check_vma=not eng.fused))
 
     U_dummy = U0[0] if U_star is None else U_star
+    with span("virtual.shard"):
+        U0, Xg, yg = jax.device_put((U0, Xg, yg),
+                                    NamedSharding(mesh, sharded))
     # one program: the scan and the final min-B
-    with span("solve.scan"):
+    with span("solve.scan", devices=D, gathers_per_iter=int(with_metrics),
+              **(wire or {})):
         out = run(U0, Xg, yg, U_dummy, *((xs,) if has_xs else ()))
     if not with_metrics:
         return out

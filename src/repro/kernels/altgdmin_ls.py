@@ -64,7 +64,8 @@ def _chol_solve_unrolled(G, c, r: int):
     forward/back substitution.  r is a static Python int (tiny: 4–10), so
     the O(r³) unroll is a handful of scalar ops — this is what lets the
     min-B solve live INSIDE the kernel instead of bouncing (G, c) to HBM
-    and re-dispatching for the gradient."""
+    and re-dispatching for the gradient.  ``ops`` solves the Gram kernel's
+    systems with it too, outside any kernel."""
     Lc = [[None] * r for _ in range(r)]
     for i in range(r):
         for j in range(i + 1):

@@ -180,7 +180,12 @@ def _ssd_scan(x, dt, A, Bm, Cm, D, *, chunk, backend):
 # ------------------------------------------------------------ MTRL LS
 
 def _solve_spd(G, c):
-    return jax.scipy.linalg.solve(G, c, assume_a="pos")
+    """b = G⁻¹ c for one tiny SPD system, by the fused kernel's own
+    unrolled Cholesky in plain array ops.  Not ``jax.scipy.linalg.solve``:
+    compiled for a multi-chip v5e mesh, its ``Cholesky`` custom call can be
+    given a result layout with the two minor dims swapped, and the factor
+    is then read transposed (wrong B on four chips, right on one)."""
+    return _ls._chol_solve_unrolled(G, c, G.shape[-1])
 
 
 def _pad_d(X, U, blk_d):

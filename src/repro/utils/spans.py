@@ -6,7 +6,8 @@ Under an active profiler (``jax.profiler.trace``, or a benchmark run
 with ``--trace 1``) the span lands in the trace beside the device's ops,
 so device idle time can be given to the phase the host was in.  Keyword
 ``counts`` become the event's stats (``serve.dispatch`` carries its
-request, slot and row counts).
+request, slot and row counts); :meth:`span.count` adds those known only
+inside the span.
 
 One ``jax.monitoring`` listener, registered on first use, adds JAX's
 compile-path events to the innermost open span of the thread that
@@ -102,7 +103,8 @@ class span:
     the compile-path events raised while it was the innermost open span
     of its thread, or None where there were none."""
 
-    __slots__ = ("compile_path", "_name", "_counts", "_ann", "_t0")
+    __slots__ = ("compile_path", "_name", "_counts", "_late", "_ann",
+                 "_t0")
 
     def __init__(self, name: str, **counts):
         if not _listening:
@@ -110,7 +112,14 @@ class span:
         self.compile_path = None
         self._name = PREFIX + name
         self._counts = counts
+        self._late: dict = {}
         self._ann = jax.profiler.TraceAnnotation(self._name, **counts)
+
+    def count(self, **counts) -> None:
+        """Add counts that the phase learns while it runs; the span
+        writes them on its event as it ends."""
+        self._counts = {**self._counts, **counts}
+        self._late = {**self._late, **counts}
 
     def __enter__(self) -> "span":
         self._ann.__enter__()
@@ -122,7 +131,7 @@ class span:
         _open().pop()
         if self._t0 is not None:
             stats = self.compile_path or dict.fromkeys(STATS, 0)
-            self._ann.set_metadata(**stats)
+            self._ann.set_metadata(**self._late, **stats)
             _recorded.append((self._name, self._t0,
                               time.perf_counter() - self._t0,
                               {**self._counts, **stats}))

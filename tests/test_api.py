@@ -48,6 +48,10 @@ def test_spec_json_round_trip():
     back2 = ExperimentSpec.from_dict(json.loads(json.dumps(spec.to_dict())))
     assert back2 == spec
     assert isinstance(back2.topology.shifts, tuple)
+    # a mesh deployment that names its chip count
+    mesh4 = dataclasses.replace(spec, substrate="mesh", n_devices=4)
+    assert ExperimentSpec.from_json(mesh4.to_json()) == mesh4
+    assert mesh4.to_dict()["n_devices"] == 4 and spec.n_devices is None
 
 
 def test_spec_from_dict_rejects_unknown_fields():

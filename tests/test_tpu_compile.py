@@ -104,3 +104,39 @@ def test_compress_topk_compiles(one_chip):
 def test_dequant_compiles(one_chip):
     _compile(lambda q, s: cp.dequant(q, s, interpret=False),
              one_chip, ((L, 600, R), jnp.int8), ((L, 1, 1), F32))
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """A mesh over the described host's four chips, in the order
+    ``jax.make_mesh`` gives a 2x2 v5e (``one_chip`` described it)."""
+    import numpy as np
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return jax.sharding.Mesh(np.array(topo.devices)[[0, 1, 3, 2]],
+                             ("nodes",))
+
+
+def test_refit_on_four_chips_holds_no_cholesky_custom_call(four_chips):
+    """The mesh tiers' final refit, compiled for four chips: the pallas
+    engine's min-B over each chip's block of 5 nodes.  Solved through
+    ``jax.scipy.linalg.solve``, that program gave its ``Cholesky``
+    custom call a result layout with the two minor dims swapped, and on
+    the chips every node's B came out wrong; the solve is now plain
+    array ops, so no such custom call is left to be laid out."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.engine import AltgdminEngine
+    eng = AltgdminEngine(backend="pallas", blk_d=BLK)
+    refit = jax.shard_map(eng.minimize_B, mesh=four_chips,
+                          in_specs=(P("nodes"),) * 3, out_specs=P("nodes"),
+                          check_vma=False)
+    nodes = NamedSharding(four_chips, P("nodes"))
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=nodes)
+            for s in ((L, 600, R), (L, TPN, N, 600), (L, TPN, N))]
+    with jax.enable_x64(False), jax.default_matmul_precision("highest"):
+        hlo = jax.jit(refit).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert 'custom_call_target="Cholesky"' not in hlo
